@@ -123,8 +123,6 @@ def train_mixture_gan(method: str, steps=1500, batch=256, lr=None, seed=0,
     the mesh's data axis (the comm_adaptive frontier's M machines)."""
     from contextlib import nullcontext
 
-    from repro.parallel.compat import set_mesh
-
     lr = METHOD_LR.get(method, 1e-3) if lr is None else lr
     cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                     hidden=128, weight_clip=0.1)
@@ -133,7 +131,7 @@ def train_mixture_gan(method: str, steps=1500, batch=256, lr=None, seed=0,
     params = mlp_gan_init(key, cfg)
     tr = make_trainer(method, cfg, lr, dq_overrides, strategy_overrides,
                       mesh=mesh)
-    with set_mesh(mesh) if mesh is not None else nullcontext():
+    with jax.set_mesh(mesh) if mesh is not None else nullcontext():
         st = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,), donate_argnums=0)
         sched = tr.strategy.schedule.runtime()

@@ -60,6 +60,30 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
+
+
+def _rerun_on_host_devices(section: str, quick: bool):
+    """Re-run one section in a child on 8 forced CPU host devices.
+
+    Only a CPU parent may do this: a parent that has touched an
+    accelerator holds it, and the child (forced onto the CPU) would
+    quietly measure the host instead. So on a chip it refuses."""
+    import subprocess
+
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"{section}: needs >= 4 devices, found {jax.device_count()} "
+            f"{jax.default_backend()} device(s); it does not fall back to "
+            "forced CPU host devices")
+    print(f"# {section}: <4 devices — re-running with 8 forced host "
+          "devices", flush=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    cmd = [sys.executable, "-m", "benchmarks.run", "--only", section] \
+        + (["--quick"] if quick else [])
+    subprocess.run(cmd, check=True, env=env)
+
 
 def _timeit(fn, *args, iters=20, warmup=3):
     for _ in range(warmup):
@@ -253,32 +277,22 @@ def bench_overlap(quick: bool):
     so any hidden time there comes from scheduler reordering only — the
     ≥50%-hidden expectation is a GPU/TPU-class statement (DESIGN.md
     §13); the artifact records the platform so readers can tell."""
-    import subprocess
-
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
     from repro.configs.base import DQConfig
     from repro.core.dqgan import DQGAN
     from repro.models.gan import GANConfig, gan_field_fn, mlp_gan_init
     from repro.obs.profile import overlap_ratio
-    from repro.parallel.compat import make_mesh, set_mesh
     from repro.strategy import (Compression, ExchangePlan, Observability,
                                 Schedule, Strategy)
 
     if jax.device_count() < 4:
-        # a single device has no wire to hide; re-exec on forced host
-        # devices (same dance as bench_comm_adaptive)
-        print("# overlap: <4 devices — re-running with 8 forced host "
-              "devices", flush=True)
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8")
-        cmd = [sys.executable, "-m", "benchmarks.run", "--only", "overlap"] \
-            + (["--quick"] if quick else [])
-        subprocess.run(cmd, check=True, env=env)
+        # a single device has no wire to hide
+        _rerun_on_host_devices("overlap", quick)
         return _load_overlap_measured()
 
     M = min(jax.device_count(), OVERLAP_M)
-    mesh = make_mesh((M,), ("data",))
+    mesh = jax.make_mesh((M,), ("data",), axis_types=(AxisType.Auto,))
     cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                     hidden=128)
     params = mlp_gan_init(jax.random.key(0), cfg)
@@ -296,7 +310,7 @@ def bench_overlap(quick: bool):
         tr = DQGAN(field_fn=gan_field_fn(cfg), dq=dq, mesh=mesh,
                    batch_spec=P(("data",)))
         out = []
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st = tr.init(params)
             step = jax.jit(tr.step, static_argnums=(3,))
             for i in range(warm + n_steps):
@@ -645,10 +659,15 @@ def bench_compression(quick: bool):
 
 # --------------------------------------------------------------------------- #
 def bench_kernels(quick: bool):
+    from repro.kernels import resolve_interpret
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.quantize import quantize_ef_blocked
     from repro.kernels.ref import flash_attention_ref, quantize_ef_ref
 
+    # interpret-mode timings time the Pallas interpreter, not the kernel
+    mode, note = (("interpret", "correctness-path; TPU perf is the target")
+                  if resolve_interpret() else
+                  ("compiled", f"device={jax.devices()[0].device_kind}"))
     R, Cc = (256, 512) if quick else (1024, 1024)
     key = jax.random.key(0)
     g = jax.random.normal(key, (R, Cc))
@@ -658,10 +677,9 @@ def bench_kernels(quick: bool):
     us_ref = _timeit(ref, g, e, r, iters=10)
     bw = 4 * 3 * R * Cc / (us_ref / 1e6) / 1e9
     row("kernels/quantize_ef_ref(jnp)", us_ref, f"gbps={bw:.2f}")
-    k_interp = jax.jit(lambda g, e, r: quantize_ef_blocked(g, e, r))
-    us_k = _timeit(k_interp, g, e, r, iters=3, warmup=1)
-    row("kernels/quantize_ef_pallas(interpret)", us_k,
-        "correctness-path; TPU perf is the target")
+    k_pallas = jax.jit(lambda g, e, r: quantize_ef_blocked(g, e, r))
+    us_k = _timeit(k_pallas, g, e, r, iters=3, warmup=1)
+    row(f"kernels/quantize_ef_pallas({mode})", us_k, note)
 
     S, D = (256, 64) if quick else (1024, 128)
     q = jax.random.normal(key, (4, S, D))
@@ -674,8 +692,7 @@ def bench_kernels(quick: bool):
         f"gflops={4*S*S*D*4/(us_ra/1e6)/1e9:.1f}")
     fa = jax.jit(lambda q, k, v: flash_attention(q, k, v))
     us_fa = _timeit(fa, q, kk, vv, iters=2, warmup=1)
-    row("kernels/flash_attention_pallas(interpret)", us_fa,
-        "correctness-path; TPU perf is the target")
+    row(f"kernels/flash_attention_pallas({mode})", us_fa, note)
 
 
 # --------------------------------------------------------------------------- #
@@ -870,26 +887,19 @@ def bench_comm_adaptive(quick: bool):
       * at the same participation 0.5, adaptive (which re-spends the
         absent workers' budget on finer bits) is no worse than static.
     """
-    import subprocess
+    from jax.sharding import AxisType
 
     from benchmarks.gan_common import train_mixture_gan
 
-    from repro.parallel.compat import make_mesh
     from repro.sched import n_participants
 
     if jax.device_count() < 4:
-        # the frontier needs real workers; re-exec on forced host devices
-        print("# comm_adaptive: <4 devices — re-running with 8 forced "
-              "host devices", flush=True)
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8")
-        cmd = [sys.executable, "-m", "benchmarks.run",
-               "--only", "comm_adaptive"] + (["--quick"] if quick else [])
-        subprocess.run(cmd, check=True, env=env)
+        # the frontier needs real workers
+        _rerun_on_host_devices("comm_adaptive", quick)
         return None
 
     M = ADAPTIVE_M if jax.device_count() >= ADAPTIVE_M else jax.device_count()
-    mesh = make_mesh((M,), ("data",))
+    mesh = jax.make_mesh((M,), ("data",), axis_types=(AxisType.Auto,))
     steps = 400 if quick else 1500
     rows = []
     for p in ADAPTIVE_PARTICIPATIONS:
@@ -1149,6 +1159,7 @@ def check_sched_regression(current: dict, baseline: dict,
 
 # --------------------------------------------------------------------------- #
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small sizes/steps (CI mode)")

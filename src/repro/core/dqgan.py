@@ -289,13 +289,17 @@ class DQGAN:
     # state construction
     # ------------------------------------------------------------------ #
     def init(self, params) -> DQState:
-        """Concrete zero state (small-scale runs/tests)."""
+        """Concrete zero state, every leaf placed with its `init_abstract`
+        sharding: the first step then sees the input shardings that later
+        steps get back from it (one trace, one compile), and each
+        per-worker slot lives on its own worker's devices."""
         sched_c = self.strategy.schedule
+        abstract = self.init_abstract(params)
         st = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype) if hasattr(s, "shape") else s,
-            self.init_abstract(params),
+            abstract,
             is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
-        )._replace(params=params, step=jnp.zeros((), jnp.int32))
+        )._replace(params=params)
         if sched_c.kind == "delayed":
             # nothing applied yet: version −τ makes the staleness metric
             # (step − version) read exactly τ from the first exchange on
@@ -319,7 +323,10 @@ class DQGAN:
             for b in layout.buckets:
                 fb[str(b.bid)]["w"] = flats[b.bid].reshape(W, b.size // W)
             st = st._replace(fsdp=fb)
-        return st
+        if self.mesh is None:
+            return st
+        return jax.tree.map(lambda x, s: jax.device_put(x, s.sharding),
+                            st, abstract)
 
     def _validate_lr_mults(self, params):
         """DQConfig.lr_mults names top-level param groups (TTUR); a typo'd
@@ -354,9 +361,14 @@ class DQGAN:
         ef_dtype = jnp.dtype(strat.compression.ef_dtype)
 
         def sds(shape, dtype, spec):
-            sharding = (
-                NamedSharding(self.mesh, spec) if self.mesh is not None else None
-            )
+            # trailing Nones dropped: the spelling jit gives its outputs, so
+            # `init`'s state keys the same compiled step as the step's own
+            # output (P(None,) and P() are one layout but two cache keys)
+            entries = list(spec)
+            while entries and entries[-1] is None:
+                entries.pop()
+            sharding = (NamedSharding(self.mesh, P(*entries))
+                        if self.mesh is not None else None)
             return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
         def pspec(x):
@@ -484,7 +496,7 @@ class DQGAN:
         )
 
         return DQState(
-            step=jax.ShapeDtypeStruct((), jnp.int32),
+            step=sds((), jnp.int32, P()),
             params=params_s,
             prev_grad=prev_grad,
             prev_update=prev_update,
@@ -588,26 +600,20 @@ class DQGAN:
                 k: rep for k in OBS.metric_keys(
                     obs_spec, self._obs_n_buckets(state.params))}
         out_specs = StepOutput(state=state_specs, metrics=metric_specs)
-        from repro.parallel.compat import key_across_boundary, shard_map
-
-        key, converted = key_across_boundary(key)
-        if converted:
-            inner = body
-
-            def body(state, batch, kd, widx_arr):
-                return inner(state, batch, jax.random.wrap_key_data(kd),
-                             widx_arr)
-
-        # worker index as a sharded input: equivalent to lax.axis_index but
-        # also usable on legacy jax, whose partial-auto shard_map cannot
-        # lower PartitionId (see parallel.compat).
+        # manual over the worker axes, auto over the rest; the replication
+        # checker is off because the worker bodies mix collectives with
+        # auto-sharded compute, which it cannot type. The worker index
+        # arrives as a sharded input: with `lax.axis_index` inside a
+        # partial-auto shard_map (an auto 'model' axis) the XLA:CPU
+        # collectives of a (pod, data, model) mesh deadlock.
         widx_arr = jnp.arange(W, dtype=jnp.int32)
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(state_specs, batch_specs, rep, wlead),
             out_specs=out_specs,
-            axis_names=axes,
+            axis_names=set(axes),
+            check_vma=False,
         )
         return fn(state, batch, key, widx_arr)
 
@@ -821,8 +827,7 @@ class DQGAN:
 
         widx = None
         if axes:
-            widx = (widx_arr[0] if widx_arr is not None
-                    else jax.lax.axis_index(axes))
+            widx = widx_arr[0]
             key = jax.random.fold_in(key, widx)
         kfield, kq = jax.random.split(jax.random.fold_in(key, state.step))
 
@@ -867,10 +872,10 @@ class DQGAN:
                     # depend on the reduced shard and wait in the thunk.
                     finish_xchg = self._start_fsdp(
                         pending, ef, fsdp_st, params, state.step, kq,
-                        axes, widx=widx, col=col)
+                        axes, col=col)
                 else:
                     finish_xchg = self._start_exchange_tree(
-                        pending, ef, plans, kq, axes, widx=widx, part=part,
+                        pending, ef, plans, kq, axes, part=part,
                         plan_sel=plan_sel, col=col, eager=False)
 
         # ---------- extrapolation to w_{t-1/2} ---------------------------- #
@@ -940,7 +945,7 @@ class DQGAN:
                 fin = (finish_xchg if finish_xchg is not None
                        else self._start_fsdp(exch_msg, ef, fsdp_st, params,
                                              state.step, kq, axes,
-                                             widx=widx, col=col))
+                                             col=col))
             with OBS.device_span("apply", self._obs_spans):
                 new_params, new_ef, new_fsdp = fin()
             new_m, new_v, new_prev_update = state.m, state.v, state.prev_update
@@ -952,7 +957,7 @@ class DQGAN:
                     qhat, new_ef = finish_xchg()
                 else:
                     qhat, new_ef = self._exchange_tree(
-                        exch_msg, ef, plans, kq, axes, widx=widx, part=part,
+                        exch_msg, ef, plans, kq, axes, part=part,
                         plan_sel=plan_sel, col=col)
             with OBS.device_span("apply", self._obs_spans):
                 new_params, new_m, new_v, new_prev_update = (
@@ -1075,18 +1080,18 @@ class DQGAN:
         return new_params, new_m, new_v, new_prev_update
 
     # ------------------------------------------------------------------ #
-    def _exchange_tree(self, message, ef, plans, key, axes, widx=None,
-                       part=None, plan_sel=None, col=None):
+    def _exchange_tree(self, message, ef, plans, key, axes, part=None,
+                       plan_sel=None, col=None):
         """Blocking exchange: start + immediate finish. The eager start
         keeps per-leaf/per-bucket op emission order identical to the
         pre-split API, so every_step/local_k (and overlap=False delayed)
         compile to bit-identical graphs."""
         return self._start_exchange_tree(
-            message, ef, plans, key, axes, widx=widx, part=part,
+            message, ef, plans, key, axes, part=part,
             plan_sel=plan_sel, col=col, eager=True)()
 
-    def _start_exchange_tree(self, message, ef, plans, key, axes, widx=None,
-                             part=None, plan_sel=None, col=None, eager=True):
+    def _start_exchange_tree(self, message, ef, plans, key, axes, part=None,
+                             plan_sel=None, col=None, eager=True):
         """Issue the exchange's compress + wire collectives and return a
         finish thunk yielding (q̂, new_ef) — the tree-level face of the
         split-phase contract (core.exchange.start/finish, DESIGN.md §13).
@@ -1103,12 +1108,11 @@ class DQGAN:
             col = OBS.NullCollector()
         if part is not None:
             return self._start_with_participation(
-                message, ef, plans, key, axes, widx, part, plan_sel, col,
-                eager)
+                message, ef, plans, key, axes, part, plan_sel, col, eager)
         if self.bucketed:
             return self._start_bucketed(message, ef, plans, key, axes,
-                                        widx=widx, plan_sel=plan_sel,
-                                        col=col, eager=eager)
+                                        plan_sel=plan_sel, col=col,
+                                        eager=eager)
         dq = self.dq
         comp = self.compressor
         exch_c = self.strategy.exchange
@@ -1132,8 +1136,7 @@ class DQGAN:
                 h = X.ExchangeHandle(pl["strategy"],
                                      lambda q=q1, ne=ne1: (q, ne))
             else:
-                h = exch_c.start(comp, pl, p, e, k, W, dq.error_feedback,
-                                 widx=widx)
+                h = exch_c.start(comp, pl, p, e, k, W, dq.error_feedback)
             if eager:
                 q, ne = exch_c.finish(h)
                 if col.enabled:
@@ -1158,7 +1161,7 @@ class DQGAN:
         return finish
 
     def _start_with_participation(self, message, ef, plans, key, axes,
-                                  widx, part, plan_sel, col, eager):
+                                  part, plan_sel, col, eager):
         """Partial participation (sched.participation, DESIGN.md §5.3):
         this worker's message and worker-side residual are masked to zero
         at START when it sits the round out — every registry compressor
@@ -1193,8 +1196,8 @@ class DQGAN:
             ef_in = mask_e1(ef)
 
         inner = self._start_exchange_tree(msg_in, ef_in, plans, key, axes,
-                                          widx=widx, plan_sel=plan_sel,
-                                          col=col, eager=eager)
+                                          plan_sel=plan_sel, col=col,
+                                          eager=eager)
 
         def finish():
             qhat, new_ef = inner()
@@ -1239,8 +1242,8 @@ class DQGAN:
     # ------------------------------------------------------------------ #
     # repro.comm flat-bucket fast path (DESIGN.md §3)
     # ------------------------------------------------------------------ #
-    def _start_bucketed(self, message, ef, plans, key, axes, widx=None,
-                        plan_sel=None, col=None, eager=True):
+    def _start_bucketed(self, message, ef, plans, key, axes, plan_sel=None,
+                        col=None, eager=True):
         """Exchange over bucket views: unsharded leaves are packed into a
         handful of flat, worker-divisible arrays (one collective each, per-
         bucket compressor from the comm planner); sharded leaves keep the
@@ -1333,7 +1336,7 @@ class DQGAN:
                                      lambda q=q1, ne=ne1: (q, ne))
             else:
                 h = exch_c.start(comp_b, plan_b, flats[b.bid], est, k, W,
-                                 dq.error_feedback, widx=widx)
+                                 dq.error_feedback)
             if eager:
                 finish_bucket(b, plan_b, est, h)
             else:
@@ -1352,7 +1355,7 @@ class DQGAN:
                                         lambda q=q1, ne=ne1: (q, ne))
             return exch_c.start(
                 base_comp, plan_leaves[s.index], leaves[s.index],
-                ef_leaves[s.index], k, W, dq.error_feedback, widx=widx)
+                ef_leaves[s.index], k, W, dq.error_feedback)
 
         skipped_started = []
         if not eager:
@@ -1401,7 +1404,7 @@ class DQGAN:
     # compressed-gradient FSDP (DESIGN.md §15)
     # ------------------------------------------------------------------ #
     def _start_fsdp(self, message, ef, fb, params, step, key, axes,
-                    widx=None, col=None):
+                    col=None):
         """One fsdp round over the flat buckets: pack → per-bucket
         (compressed) reduce-scatter of the gradient message (worker-side
         e1 EF, per-bucket compressor from the comm planner) → shard-owner
@@ -1456,8 +1459,7 @@ class DQGAN:
                 est["e1"] = e1_flats[b.bid]
             k = jax.random.fold_in(key, 100_000 + b.bid)
             h = exch_c.start_reduce_scatter(
-                comp_b, flats[b.bid], est, k, W, dq.error_feedback,
-                widx=widx)
+                comp_b, flats[b.bid], est, k, W, dq.error_feedback)
             started.append((b, est, h, jax.random.fold_in(k, 1)))
 
         def finish():
@@ -1477,7 +1479,7 @@ class DQGAN:
                     age = jnp.zeros_like(ag_in)
                 h_ag = exch_c.start_all_gather_shard(
                     mom_comp, ag_in, age.astype(jnp.float32), kag, W,
-                    mom_c.error_feedback, widx=widx)
+                    mom_c.error_feedback)
                 full, new_age = exch_c.finish(h_ag)
                 ent["age"] = new_age.astype(jnp.float32)
                 new_fb[str(b.bid)] = ent

@@ -61,7 +61,7 @@ def compress_with_ef(
     VMEM-resident pass instead of ~4 jnp kernels. The payload format is
     identical; only the stochastic draws differ (same distribution).
     ``allow_fused=False`` opts out (e.g. under vmapped workers, where the
-    interpret-mode pallas_call must not be batched).
+    pallas_call must not be batched).
     """
     if use_ef and allow_fused and fused_compatible(compressor, message):
         return fused_quantize_ef(message, e_prev, key,
@@ -76,8 +76,7 @@ def compress_with_ef(
     return payload, m_hat, e_new
 
 
-def fused_quantize_ef(message_flat, e_prev, key, *, levels=127,
-                      interpret: bool = True):
+def fused_quantize_ef(message_flat, e_prev, key, *, levels=127):
     """Single-HBM-pass EF + int8 quantization for a flat comm bucket via the
     Pallas kernel (kernels.quantize.quantize_ef_flat) — the fused equivalent
     of compress_with_ef(StochasticQuant(bits=8, norm="linf",
@@ -95,8 +94,7 @@ def fused_quantize_ef(message_flat, e_prev, key, *, levels=127,
     m32 = message_flat.astype(jnp.float32)
     rand = jax.random.uniform(key, m32.shape)
     codes, scales, e_new = quantize_ef_flat(
-        m32, e_prev.astype(jnp.float32), rand,
-        levels=levels, interpret=interpret)
+        m32, e_prev.astype(jnp.float32), rand, levels=levels)
     R = scales.shape[0]
     m_hat = (codes.reshape(R, -1).astype(jnp.float32)
              * (scales[:, None] / levels)).reshape(message_flat.shape)

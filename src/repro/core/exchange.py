@@ -134,35 +134,16 @@ def ef_state_zeros(plan: dict, shape, dtype, n_workers: int, use_ef: bool):
 
 
 # --------------------------------------------------------------------------- #
-# collectives (with a legacy-jax emulation path)
+# collectives
 # --------------------------------------------------------------------------- #
-_HAS_MODERN_SHARD_MAP = hasattr(jax, "shard_map")
-
-
 def _mean_axes(x, axes):
     return jax.lax.pmean(x, axes)
 
 
-def _all_gather(x, axes, W, widx):
-    """all_gather over the worker axes. On old jax (experimental shard_map
-    with partial-auto), the real all-gather trips an XLA partitioner CHECK;
-    when a worker index is provided we emulate it as psum(onehot ⊗ x) —
-    W× the traffic, correctness-only (the CI/CPU regime)."""
-    if _HAS_MODERN_SHARD_MAP or widx is None:
-        return jax.lax.all_gather(x, axes)
-    onehot = (jnp.arange(W) == widx).astype(x.dtype)
-    return jax.lax.psum(onehot.reshape((W,) + (1,) * x.ndim) * x[None], axes)
-
-
-def _all_to_all(c, axes, W, widx):
-    """all_to_all with leading source-worker dim (split/concat axis 0).
-    Legacy emulation: gather everyone's chunks, keep own column."""
-    if _HAS_MODERN_SHARD_MAP or widx is None:
-        return jax.lax.all_to_all(c, axes, split_axis=0, concat_axis=0,
-                                  tiled=False)
-    gathered = _all_gather(c, axes, W, widx)  # (src, chunk, ...)
-    return jax.lax.dynamic_index_in_dim(gathered, widx, axis=1,
-                                        keepdims=False)
+def _all_to_all(c, axes):
+    """all_to_all with leading source-worker dim (split/concat axis 0)."""
+    return jax.lax.all_to_all(c, axes, split_axis=0, concat_axis=0,
+                              tiled=False)
 
 
 # --------------------------------------------------------------------------- #
@@ -202,12 +183,10 @@ def start_exchange(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
-    widx=None,
 ) -> ExchangeHandle:
     """Issue the wire collectives for one tensor; return a handle whose
     `finish_exchange` yields (q̂, new_ef_state). Runs under
-    shard_map(axes). ``widx`` (this worker's index over `axes`) enables
-    the legacy-jax collective emulation; optional on modern jax.
+    shard_map(axes).
 
     Split points per strategy (start | finish):
       exact     : pmean(p)                              | identity
@@ -237,7 +216,7 @@ def start_exchange(
         if use_ef:
             new_state["e1"] = e_new
         gathered = jax.tree.map(
-            lambda x: _all_gather(x, axes, n_workers, widx), payload)
+            lambda x: jax.lax.all_gather(x, axes), payload)
 
         def _finish_allgather():
             deq = jax.vmap(
@@ -249,7 +228,7 @@ def start_exchange(
 
     if strategy == "two_phase":
         return _start_two_phase(compressor, plan, p, ef_state, new_state, key,
-                                axes, n_workers, use_ef, widx)
+                                axes, n_workers, use_ef)
 
     raise ValueError(f"unknown strategy {strategy!r}")
 
@@ -269,7 +248,6 @@ def exchange_leaf(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
-    widx=None,
 ):
     """Blocking shim: start + immediate finish (deprecated spelling).
 
@@ -280,12 +258,11 @@ def exchange_leaf(
     module-level `start_exchange`/`finish_exchange` pair.
     """
     return finish_exchange(start_exchange(
-        compressor, plan, p, ef_state, key, axes, n_workers, use_ef,
-        widx=widx))
+        compressor, plan, p, ef_state, key, axes, n_workers, use_ef))
 
 
 def _start_two_phase(compressor, plan, p, ef_state, new_state, key, axes, W,
-                     use_ef, widx=None) -> ExchangeHandle:
+                     use_ef) -> ExchangeHandle:
     ax = plan["chunk_axis"]
     orig_shape = p.shape
     # ---- phase 1: worker-side compress + all-to-all ------------------------ #
@@ -300,7 +277,7 @@ def _start_two_phase(compressor, plan, p, ef_state, new_state, key, axes, W,
         e_new = (x - x_hat).reshape((orig_shape[ax],) + _rest(orig_shape, ax))
         new_state["e1"] = jnp.moveaxis(e_new, 0, ax).astype(e1.dtype)
     # all-to-all: leading dim becomes the source-worker index, int8 on the wire
-    moved = jax.tree.map(lambda c: _all_to_all(c, axes, W, widx), payload)
+    moved = jax.tree.map(lambda c: _all_to_all(c, axes), payload)
     contrib = jax.vmap(
         lambda pl: compressor.decompress(pl, x.shape[1:], jnp.float32)
     )(moved)
@@ -312,7 +289,7 @@ def _start_two_phase(compressor, plan, p, ef_state, new_state, key, axes, W,
     )
     del chunk_hat
     new_state["e2"] = e2_new.reshape(ef_state["e2"].shape).astype(ef_state["e2"].dtype)
-    gathered = jax.tree.map(lambda c: _all_gather(c, axes, W, widx), payload2)
+    gathered = jax.tree.map(lambda c: jax.lax.all_gather(c, axes), payload2)
 
     def _finish_two_phase():
         chunks = jax.vmap(
@@ -333,19 +310,6 @@ def _rest(shape, ax):
 # --------------------------------------------------------------------------- #
 # fsdp split-phase primitives (DESIGN.md §15)
 # --------------------------------------------------------------------------- #
-def _psum_scatter(x, axes, W, widx):
-    """True reduce-scatter over the worker axes: (d,) -> (d/W,), worker w
-    receiving sum_m x_m[w·d/W:(w+1)·d/W]. Legacy-jax emulation: full psum
-    + slice at the worker's own chunk (W× the traffic, correctness-only —
-    the same CI/CPU regime as `_all_gather`'s emulation)."""
-    if _HAS_MODERN_SHARD_MAP or widx is None:
-        return jax.lax.psum_scatter(x, axes, scatter_dimension=0,
-                                    tiled=True)
-    s = jax.lax.psum(x, axes)
-    chunk = x.shape[0] // W
-    return jax.lax.dynamic_slice_in_dim(s, widx * chunk, chunk)
-
-
 def start_reduce_scatter(
     compressor: C.Compressor,
     kind: str,
@@ -355,7 +319,6 @@ def start_reduce_scatter(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
-    widx=None,
 ) -> ExchangeHandle:
     """The fsdp gradient leg: (compressed) reduce-scatter of one flat,
     worker-divisible bucket (DESIGN.md §15.2). ``p`` is (d,) with
@@ -386,7 +349,9 @@ def start_reduce_scatter(
             new_state["e1"] = e_new.astype(e1.dtype)
         return _resolved(kind, p_hat.astype(p.dtype), new_state)
     if kind == "exact":
-        q = _psum_scatter(p, axes, W, widx) / W
+        # reduce-scatter: worker w receives sum_m p_m[w·d/W:(w+1)·d/W]
+        q = jax.lax.psum_scatter(p, axes, scatter_dimension=0,
+                                 tiled=True) / W
         return _resolved(kind, q.astype(p.dtype), new_state)
     if kind != "two_phase":
         raise ValueError(
@@ -404,7 +369,7 @@ def start_reduce_scatter(
         )(payload)
         new_state["e1"] = (x - x_hat).reshape(-1).astype(e1.dtype)
     # int8 codes on the wire; leading dim becomes the source-worker index
-    moved = jax.tree.map(lambda c: _all_to_all(c, axes, W, widx), payload)
+    moved = jax.tree.map(lambda c: _all_to_all(c, axes), payload)
 
     def _finish_rs():
         contrib = jax.vmap(
@@ -423,7 +388,6 @@ def start_all_gather_shard(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
-    widx=None,
 ) -> ExchangeHandle:
     """The fsdp return leg: (compressed) all-gather of one owner shard —
     the quantized optimizer-state/parameter exchange of arXiv 2004.14180
@@ -440,7 +404,7 @@ def start_all_gather_shard(
             return c_hat.astype(shard.dtype), new_ef
         return ExchangeHandle("allgather_shard", _finish_local)
     del c_hat
-    gathered = jax.tree.map(lambda c: _all_gather(c, axes, W, widx),
+    gathered = jax.tree.map(lambda c: jax.lax.all_gather(c, axes),
                             payload)
 
     def _finish_ag():

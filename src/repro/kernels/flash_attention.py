@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
                   causal: bool, sm_scale: float):
@@ -25,8 +27,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(carry, j):
         m_prev, l_prev, acc = carry
-        k = jax.lax.dynamic_slice_in_dim(k_ref[0], j * bk, bk, 0)
-        v = jax.lax.dynamic_slice_in_dim(v_ref[0], j * bk, bk, 0)
+        # slice the ref, not a loaded value: Mosaic lowers a ref read at a
+        # dynamic offset but has no dynamic_slice on vector values
+        k = k_ref[0, pl.ds(pl.multiple_of(j * bk, bk), bk), :]
+        v = v_ref[0, pl.ds(pl.multiple_of(j * bk, bk), bk), :]
         s = q @ k.astype(jnp.float32).T                   # (BQ, BK)
         if causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -54,7 +58,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: bool | None = None):
     """q, k, v: (BH, S, D) — batch*heads flattened, same kv heads as q.
     Returns (BH, S, D)."""
     BH, S, D = q.shape
@@ -73,7 +77,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
@@ -114,7 +118,7 @@ def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref, *, bs: int,
 
 
 def paged_flash_attention(q, pool_k, pool_v, table, lengths, *,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Decode-step attention over a paged KV cache.
 
     q:       (B, K, G, D) — one query token per row, grouped GQA heads.
@@ -124,6 +128,11 @@ def paged_flash_attention(q, pool_k, pool_v, table, lengths, *,
     lengths: (B,) int32 — valid context per row (entries at positions
              >= lengths[row] are masked; rows with length 0 return 0).
     Returns (B, K, G, D).
+
+    The TPU compiler refuses this kernel today: the (1, MAXB) table block
+    breaks the (8, 128) tiling rule, and compiling it needs the tables as
+    scalar-prefetch operands. The serving default (the "gather" decode in
+    models/layers.py) does not use it.
     """
     B, Kh, G, D = q.shape
     NB, bs = pool_k.shape[0], pool_k.shape[1]
@@ -143,5 +152,5 @@ def paged_flash_attention(q, pool_k, pool_v, table, lengths, *,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kh, G, D), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(table.astype(jnp.int32), lengths2, q, pool_k, pool_v)

@@ -13,9 +13,9 @@ intermediates). The fused kernel does one VMEM-resident pass:
 
 Tiles are (BR, C) with C a multiple of 128 (lane width) and BR a multiple
 of 8 (sublane) — MXU/VPU-aligned per the TPU tiling rules. Randomness is
-passed in as a uniform tensor so the kernel is bit-reproducible on CPU
-(interpret=True) and TPU alike; on TPU the pltpu PRNG could generate it
-in-kernel (saves one read stream — noted in EXPERIMENTS.md §Perf).
+passed in as a uniform tensor so the kernel is bit-reproducible in the
+interpreter (CPU) and compiled (TPU) alike; on TPU the pltpu PRNG could
+generate it in-kernel and save one read stream.
 """
 from __future__ import annotations
 
@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _qef_body(g, e, r, levels, e_dtype):
@@ -61,8 +63,11 @@ def _quantize_ef_kernel_dyn(g_ref, e_ref, r_ref, lv_ref, codes_ref,
 
 
 def quantize_ef_blocked(g, e, rand, *, levels=127, block_rows: int = 256,
-                        interpret: bool = True):
-    """g, e, rand: (R, C) with C % 128 == 0 and R % block_rows == 0.
+                        interpret: bool | None = None):
+    """g, e, rand: (R, C) with C % 128 == 0. Blocks are (block_rows, C)
+    with block_rows a multiple of 8 or R itself; when block_rows does not
+    divide R the last block is ragged — rows are independent, so its
+    out-of-range rows are computed on padding and their writes dropped.
     Returns (codes int8 (R,C), scales f32 (R,1), e_new (R,C)).
 
     ``levels`` may be a Python int (baked into the kernel — the original
@@ -71,8 +76,9 @@ def quantize_ef_blocked(g, e, rand, *, levels=127, block_rows: int = 256,
     R, C = g.shape
     assert C % 128 == 0, f"lane-align C to 128, got {C}"
     br = min(block_rows, R)
-    assert R % br == 0, (R, br)
-    grid = (R // br,)
+    assert br == R or br % 8 == 0, f"block rows {br} of {R}: not 8-aligned"
+    grid = (pl.cdiv(R, br),)
+    interpret = resolve_interpret(interpret)
 
     def idx(i):
         return (i, 0)
@@ -113,18 +119,19 @@ def quantize_ef_blocked(g, e, rand, *, levels=127, block_rows: int = 256,
 def bucket_tile_shape(n: int):
     """(R, C, block_rows) tiling for a flat comm bucket of n elements.
     buckets.build_layout pads every bucket to a multiple of
-    n_workers·LANE·SUBLANE = n_workers·1024, so C = 1024 always divides; the
-    block-row count is the largest divisor of R up to 256."""
+    n_workers·LANE·SUBLANE = n_workers·1024, so C = 1024 always divides.
+    The block-row count is R itself up to 256 rows (a full-extent block is
+    always legal), else 256 — a multiple of 8, as the TPU's (8, 128)
+    tiling rule asks of every operand, the int8 codes included — with a
+    ragged last block where 256 does not divide R."""
     C = 1024 if n % 1024 == 0 else 128
     assert n % C == 0, f"bucket size {n} not lane-aligned"
     R = n // C
-    br = min(256, R)
-    while R % br:
-        br -= 1
-    return R, C, br
+    return R, C, min(256, R)
 
 
-def quantize_ef_flat(g, e, rand, *, levels=127, interpret: bool = True):
+def quantize_ef_flat(g, e, rand, *, levels=127,
+                     interpret: bool | None = None):
     """Fused quantize+EF over a flat comm bucket (1-D, lane-aligned size).
 
     Tiles the bucket as (R, 1024) rows — each row is one scale block, i.e.

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the production meshes, prove the sharding config is coherent, and dump the
 roofline ingredients (FLOPs, bytes, per-category collective bytes, memory
@@ -14,10 +11,17 @@ Full sweep (subprocess per combo, cached by output file):
 """
 import argparse
 import json
+import os
 import re
 import subprocess
 import sys
 import time
+
+# 512 forced CPU host devices stand in for the production meshes. Set in
+# main(), before the first jax call (jax fixes the device count at its
+# first init), and never at import: importers keep their own XLA_FLAGS.
+HOST_ENV = {"JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=512"}
 
 # v5e roofline constants (per chip)
 PEAK_FLOPS = 197e12      # bf16
@@ -159,8 +163,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
     rec["chips"] = n_chips
     t0 = time.time()
     try:
-        from repro.parallel.compat import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             max_seq = shape.seq_len if not cfg.use_rope else 0
             params_sds, pspecs = S.abstract_params(cfg, mesh, layout,
                                                    max_seq or 8)
@@ -310,7 +313,7 @@ def driver(args):
             time.sleep(5)
         procs = [p for p in procs if p.poll() is None]
         print("RUN", arch, sh, "multi" if mp else "single", flush=True)
-        procs.append(subprocess.Popen(cmd))
+        procs.append(subprocess.Popen(cmd, env=dict(os.environ, **HOST_ENV)))
     for p in procs:
         p.wait()
     # summary
@@ -326,6 +329,7 @@ def driver(args):
 
 
 def main():
+    os.environ.update(HOST_ENV)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

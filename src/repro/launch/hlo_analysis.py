@@ -35,6 +35,9 @@ _COLL_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
              "collective-permute")
 _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->")
 _COMMENT = re.compile(r"/\*.*?\*/")
+# a layout after a shape: CPU `f32[8,4]{1,0}`, TPU `f32[64]{0:T(128)S(1)}`.
+# Its tiling parens would otherwise end the result-type segment early.
+_LAYOUT = re.compile(r"\]\{[^{}]*\}")
 _CALLED = re.compile(
     r"(?:calls=|condition=|body=|to_apply=|branch_computations=\{)"
     r"\s*%?([\w.\-]+(?:\s*,\s*%?[\w.\-]+)*)")
@@ -55,7 +58,7 @@ def parse_computations(txt: str) -> Dict[str, List[str]]:
     comps: Dict[str, List[str]] = {}
     cur = None
     for line in txt.splitlines():
-        stripped = _COMMENT.sub("", line).strip()
+        stripped = _LAYOUT.sub("]", _COMMENT.sub("", line)).strip()
         if not stripped:
             continue
         if ("->" in stripped and "{" in stripped and "=" not in
@@ -243,7 +246,7 @@ def scope_costs(txt: str, prefix: str = "repro.obs/") -> Dict[str, dict]:
         phase = tail.split("/", 1)[0].split('"', 1)[0]
         if not phase:
             continue
-        stripped = _COMMENT.sub("", line).strip()
+        stripped = _LAYOUT.sub("]", _COMMENT.sub("", line)).strip()
         # the result type sits after `=` and before the op's paren:
         #   %name = f32[8,128]{1,0} fusion(...), metadata={op_name=...}
         if "=" in stripped:

@@ -6,7 +6,8 @@ from __future__ import annotations
 import os
 import warnings
 
-from repro.parallel.compat import AxisType, make_mesh as _make_mesh
+import jax
+from jax.sharding import AxisType
 
 # Per-platform XLA flags that let the compiler overlap the split-phase
 # exchange (DESIGN.md §13) with field compute. Only flags verified to
@@ -46,7 +47,6 @@ def enable_overlap_flags(platform: str = "cpu") -> tuple:
         raise ValueError(
             f"unknown platform {platform!r}; have "
             f"{sorted(OVERLAP_XLA_FLAGS)}")
-    import jax
     monitoring = getattr(jax, "_src", None)
     backends = getattr(getattr(monitoring, "xla_bridge", None),
                        "_backends", None)
@@ -82,12 +82,12 @@ def make_production_mesh(*, multi_pod: bool = False, override: str = ""):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
     """Small mesh for unit tests (requires xla_force_host_platform_device_count)."""
-    return _make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def worker_axes_for(layout: str, multi_pod: bool):

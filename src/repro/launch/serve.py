@@ -18,6 +18,7 @@ import time
 import jax
 
 import repro.configs as cfgs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as lm
 from repro.serve import (
     Engine,
@@ -48,6 +49,7 @@ def build_serve_config(prompt_len: int, gen: int, batch: int) -> ServeConfig:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

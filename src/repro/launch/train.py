@@ -78,6 +78,8 @@ from __future__ import annotations
 import argparse
 import time
 import zipfile
+from contextlib import nullcontext
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -89,14 +91,33 @@ from repro import strategy as strategy_api
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.data import lm_batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.parallel import sharding as shd
-from repro.parallel.compat import set_mesh
 from repro.sched import clock as sclock
 from repro.sched import straggler as sstrag
 
 
+class TrainRun(NamedTuple):
+    """What `run` leaves behind: the log rows, the jitted step, and the
+    last state/batch/key it was called with (enough to lower the step
+    again, e.g. to read its compiled HLO under `mesh`)."""
+    history: list
+    step: Any
+    state: Any
+    batch: Any
+    key: Any
+    mesh: Any
+
+
 def main(argv=None):
+    """CLI entry point; returns the log rows."""
+    return run(argv).history
+
+
+def run(argv=None) -> TrainRun:
+    """Parse `argv` as the CLI does, train, and return a `TrainRun`."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -163,25 +184,34 @@ def main(argv=None):
         ap.error(str(e))
     sched = strat.schedule.runtime()
 
+    key = jax.random.key(args.seed)
+    params = bundle.init(key, max_seq=args.seq)
     if n_dev > 1:
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
 
-        from repro.parallel.compat import make_mesh
-        # fsdp shards optimizer state over the data axis and needs every
-        # leaf in a flat bucket — tensor ('model') parallelism would
-        # leave sharded leaves outside the bucketing, so it keeps a pure
-        # data mesh (DESIGN.md §15.1)
-        model_n = (2 if n_dev % 2 == 0 and n_dev > 2
-                   and not strat.exchange.fsdp else 1)
-        mesh = make_mesh((n_dev // model_n, model_n), ("data", "model"))
+        # A 'model' axis only where the sharding rules split some leaf
+        # over it, 2 wide when the device count allows. fsdp shards
+        # optimizer state over the data axis and needs every leaf in a
+        # flat bucket, so it keeps that axis at size 1 (DESIGN.md §15.1).
+        # The GAN configs have no such leaf: a pure data mesh, every
+        # device a paper-worker, and a shard_map manual over every mesh
+        # axis — which the fused Pallas kernel needs, since the TPU
+        # compiler cannot partition a Mosaic kernel over an auto axis.
+        if shd.uses_axis(shd.param_specs(params, cfg, "dp"), "model"):
+            model_n = (2 if n_dev % 2 == 0 and n_dev > 2
+                       and not strat.exchange.fsdp else 1)
+            mesh = jax.make_mesh((n_dev // model_n, model_n),
+                                 ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
+        else:
+            mesh = jax.make_mesh((n_dev,), ("data",),
+                                 axis_types=(AxisType.Auto,))
         bspec = P(("data",))
 
     dq = DQConfig.from_strategy(
         strat, optimizer=args.optimizer, lr=args.lr,
         message="update" if args.optimizer == "omd" else "grad",
     )
-    key = jax.random.key(args.seed)
-    params = bundle.init(key, max_seq=args.seq)
     if mesh is not None:
         pspecs = shd.param_specs(params, cfg, "dp", mesh)
         shards = shd.shardings(pspecs, mesh)
@@ -297,12 +327,13 @@ def main(argv=None):
         next(it)
 
     history = []
+    batch = None
     t0 = time.time()
     wall_series = None
     warm_variants = set()  # do_exchange values whose jit variant compiled
     interval_s = 0.0       # synced wall time since the last timing event
     interval_n = 0
-    ctx = set_mesh(mesh) if mesh is not None else _null()
+    ctx = jax.set_mesh(mesh) if mesh is not None else nullcontext()
     with ctx:
         for i in range(start, args.steps):
             with obs_api.host_span("data", obs_spans), \
@@ -389,7 +420,7 @@ def main(argv=None):
         save_ckpt(args.checkpoint, state,
                   int(jax.device_get(state.step)))
         print(f"saved DQState to {args.checkpoint}")
-    return history
+    return TrainRun(history, step, state, batch, key, mesh)
 
 
 def gan_batch_iterator(seed, batch, cfg):
@@ -402,14 +433,6 @@ def gan_batch_iterator(seed, batch, cfg):
         yield {"real": procedural_images(jax.random.fold_in(key, i), batch,
                                          cfg.image_size, cfg.channels)}
         i += 1
-
-
-class _null:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
 
 
 if __name__ == "__main__":

@@ -27,9 +27,6 @@ pure text analysis — runnable on host CI devices, no hardware profiler:
 * `check_fsdp_structure(...)` — ZeRO-shaped assertions: the exchange
   step lowers reduce-scatter/all-to-all + all-gather (not whole-payload
   all-reduce); with ``compressed=True`` the wire payload is int8.
-  Modern shard_map lowerings only — the legacy psum_scatter emulation
-  lowers everything to all-reduce (guard on
-  ``core.exchange._HAS_MODERN_SHARD_MAP``).
 * `exchange_field_independence(txt)` — the overlap invariant on any
   backend: no exchange-scoped collective transitively consumes a
   field-scoped op, so the scheduler is FREE to run wire and compute
@@ -65,6 +62,21 @@ def compiled_text(jitfn, *args) -> str:
     """Optimized (post-SPMD, per-device) HLO text of a jitted callable
     on the given (possibly abstract) arguments."""
     return jitfn.lower(*args).compile().as_text()
+
+
+_FRAME_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*\n?",
+    re.M)
+_LOCATION = re.compile(
+    r'(?:,? ?source_file="[^"]*"|,? ?source_line=\d+|,? ?stack_frame_id=\d+)')
+
+
+def strip_locations(txt: str) -> str:
+    """HLO text without its Python source locations (the stack-frame
+    tables and the per-op source_file/source_line/stack_frame_id
+    metadata), which depend on the checkout path and the calling script.
+    Everything else — ops, shapes, op_name scopes — is kept."""
+    return _LOCATION.sub("", _FRAME_TABLES.sub("", txt))
 
 
 def collective_summary(txt: str) -> dict:
@@ -430,10 +442,6 @@ def check_fsdp_structure(exchange_txt: str,
     all-reduce bytes that remain should be scalar metrics (loss,
     grad_norm psums), small next to the scatter/gather payload. With
     ``compressed=True`` the wire payload must additionally be int8.
-
-    Only meaningful on a modern shard_map lowering — the legacy
-    emulation expands psum_scatter to all-reduce + dynamic-slice, so
-    callers must guard on ``core.exchange._HAS_MODERN_SHARD_MAP``.
     Returns {"ok": bool, "violations": [...], ...evidence};
     `assert_fsdp_structure` raises on violations."""
     violations: List[str] = []

@@ -12,12 +12,13 @@ Two layouts (DESIGN.md §4):
 
 Rules are by parameter path name:
   column-parallel (output dim on 'model'): q k v gate up in_x in_gate z x
-      B C dt W_a W_i unembed fc
+      B C dt W_a W_i unembed
   row-parallel (input dim on 'model'):     o down out
   expert-parallel (expert dim on 'model'): gate_proj up_proj down_proj
   vocab-sharded:                           embed
   replicated:                              norms, biases of row-parallel,
-                                           conv, scalars, router
+                                           conv, scalars, router, and
+                                           every GAN leaf (fc, conv c*)
 Stacked layer params (under 'scan') get a leading None for the L axis —
 which is also the two_phase exchange's favourite chunk axis.
 """
@@ -30,7 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 COL = {"q", "k", "v", "gate", "up", "in_x", "in_gate", "z", "x", "B", "C",
-       "dt", "W_a", "W_i", "unembed", "fc"}
+       "dt", "W_a", "W_i", "unembed"}
 ROW = {"o", "down", "out"}
 EXPERT = {"gate_proj", "up_proj", "down_proj"}
 
@@ -96,6 +97,14 @@ def param_spec(path, leaf, mode: str) -> P:
         return lead(())
 
     return lead(())
+
+
+def uses_axis(specs, axis: str) -> bool:
+    """True when some spec in the tree shards a dimension over `axis`."""
+    return any(
+        ent == axis or (isinstance(ent, tuple) and axis in ent)
+        for spec in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
+        for ent in spec)
 
 
 def sanitize_spec(spec: P, shape, mesh) -> P:

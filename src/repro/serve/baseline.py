@@ -32,14 +32,14 @@ class SequentialGenerator:
     """One request at a time over a dense cache; same tokens as Engine."""
 
     def __init__(self, cfg, serve_cfg: ServeConfig, params, *,
-                 compression=None, seed: int = 0, interpret: bool = True):
+                 compression=None, seed: int = 0):
         check_model_servable(cfg)
         self.cfg = cfg
         self.scfg = serve_cfg
         self.weight_meta = None
         if compression is not None:
             self.weight_meta, self._weights = quantize_weights(
-                params, compression, seed=seed, interpret=interpret)
+                params, compression, seed=seed)
         else:
             self._weights = params
         self._base_key = jax.random.key(seed)

@@ -184,8 +184,7 @@ class Engine:
     """
 
     def __init__(self, cfg, serve_cfg: ServeConfig, params, *,
-                 compression=None, seed: int = 0, attn_impl: str = "gather",
-                 interpret: bool = True):
+                 compression=None, seed: int = 0, attn_impl: str = "gather"):
         check_model_servable(cfg)
         if attn_impl not in ("gather", "pallas"):
             raise ServeError(f"attn_impl must be gather|pallas, "
@@ -196,7 +195,7 @@ class Engine:
         self.weight_meta = None
         if compression is not None:
             self.weight_meta, self._weights = quantize_weights(
-                params, compression, seed=seed, interpret=interpret)
+                params, compression, seed=seed)
         else:
             self._weights = params
 

@@ -113,8 +113,7 @@ def _bucket_levels(plan: CommPlan, layout: BucketLayout) -> Tuple[int, ...]:
     return tuple(levels)
 
 
-def quantize_weights(params, compression, *, seed: int = 0,
-                     interpret: bool = True):
+def quantize_weights(params, compression, *, seed: int = 0):
     """One-shot load-time quantization.
 
     Returns (meta, payload): payload is a pytree of device arrays
@@ -150,7 +149,7 @@ def quantize_weights(params, compression, *, seed: int = 0,
                                       flat.shape)
             codes, scales, _ = quantize_ef_flat(
                 flat, jnp.zeros_like(flat), rand,
-                levels=levels[b.bid], interpret=interpret)
+                levels=levels[b.bid])
             payload[f"b{b.bid}"] = {"codes": codes, "scales": scales}
         return payload
 

@@ -318,23 +318,23 @@ class ExchangePlan:
         return self.parallelism == "fsdp"
 
     def start_reduce_scatter(self, compressor, p, ef_state: dict, key,
-                             n_workers: int, use_ef: bool, widx=None):
+                             n_workers: int, use_ef: bool):
         """Issue the (compressed) reduce-scatter of one flat bucket over
         this plan's worker axes; the handle finishes to this worker's
         mean shard (DESIGN.md §15.2)."""
         from repro.core import exchange as X
         return X.start_reduce_scatter(
             compressor, self.kind, p, ef_state, key, self.worker_axes,
-            n_workers, use_ef, widx=widx)
+            n_workers, use_ef)
 
     def start_all_gather_shard(self, compressor, shard, ag_ef, key,
-                               n_workers: int, use_ef: bool, widx=None):
+                               n_workers: int, use_ef: bool):
         """Issue the (compressed) all-gather of one owner shard; the
         handle finishes to (full flat bucket, new owner EF)."""
         from repro.core import exchange as X
         return X.start_all_gather_shard(
             compressor, shard, ag_ef, key, self.worker_axes, n_workers,
-            use_ef, widx=widx)
+            use_ef)
 
     # ---- split-phase surface (DESIGN.md §13) -------------------------- #
     @property
@@ -346,13 +346,12 @@ class ExchangePlan:
         return X.plan_has_owner_ef({"strategy": self.kind})
 
     def start(self, compressor, plan: dict, p, ef_state: dict, key,
-              n_workers: int, use_ef: bool, widx=None):
+              n_workers: int, use_ef: bool):
         """Issue the wire collectives for one tensor under this plan's
         worker axes; returns a `core.exchange.ExchangeHandle`."""
         from repro.core import exchange as X
         return X.start_exchange(compressor, plan, p, ef_state, key,
-                                self.worker_axes, n_workers, use_ef,
-                                widx=widx)
+                                self.worker_axes, n_workers, use_ef)
 
     def finish(self, handle):
         """(q̂, new_ef_state) from a handle returned by `start`."""

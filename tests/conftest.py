@@ -10,11 +10,14 @@ sys.path.insert(0, SRC)
 
 
 def run_multidevice(script: str, n_devices: int = 8, timeout: int = 560):
-    """Run a python snippet in a subprocess with a forced host device count.
+    """Run a python snippet in a CPU subprocess with a forced host device
+    count.
 
     Needed because jax locks the device count at first init — the main test
-    process stays single-device (per the dry-run isolation rule)."""
+    process stays single-device (per the dry-run isolation rule). The child
+    is held to the CPU so the suite never reaches for an accelerator."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", script], env=env,

@@ -18,14 +18,13 @@ import json
 import os
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.launch.hlo_analysis import scope_costs
 from repro.models.gan import GANConfig, gan_field_fn, mlp_gan_init
 from repro.obs import hlo as ohlo
-from repro.parallel.compat import make_mesh, set_mesh
 from repro.strategy import (
     Compression,
     ExchangePlan,
@@ -52,7 +51,7 @@ def build(schedule, mesh, cfg, overlap=False):
 def main():
     assert jax.device_count() >= 8, \
         "run with XLA_FLAGS=--xla_force_host_platform_device_count=8"
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                     hidden=128)
     key = jax.random.key(0)
@@ -61,6 +60,7 @@ def main():
     expected = {}
 
     def dump(fname, txt):
+        txt = ohlo.strip_locations(txt)
         with gzip.open(os.path.join(FIX, fname), "wt",
                        compresslevel=9) as fh:
             fh.write(txt)
@@ -76,7 +76,7 @@ def main():
             ("delayed_tau4", Schedule.delayed(tau=4), False),
             ("delayed_tau4_overlap", Schedule.delayed(tau=4), True)]:
         tr = build(schedule, mesh, cfg, overlap=overlap)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st = tr.init(params)
             step = jax.jit(tr.step, static_argnums=(3,))
             ex = ohlo.compiled_text(step, st, batch, jax.random.key(7),

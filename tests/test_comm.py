@@ -348,13 +348,12 @@ def test_bucketed_training_with_fused_compressor():
 # --------------------------------------------------------------------------- #
 BUCKETED_EQUIV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16, hidden=128)
 key = jax.random.key(0)
 params = mlp_gan_init(key, cfg)
@@ -365,7 +364,7 @@ def run(comm_plan, exch, comp, steps=4):
                   comm_plan=comm_plan, bucket_mb=0.25)
     tr = DQGAN(field_fn=gan_field_fn(cfg), dq=dq, mesh=mesh,
                batch_spec=P(("data",)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(tr.step)
         for i in range(steps):

@@ -7,8 +7,7 @@ pytestmark = pytest.mark.multidevice
 
 DELTA1_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh, shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 
@@ -17,7 +16,7 @@ def field(params, batch, rng):
     x, y = params["x"], params["y"]
     return {"x": A @ y, "y": -(A.T @ x)}, {"loss": x @ A @ y}
 
-mesh = make_mesh((2,2,2), ("pod","data","model"))
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,) * 3)
 params = {"x": jnp.ones(4), "y": jnp.ones(4)}
 pspecs = {"x": P(), "y": P()}
 batch = jnp.zeros((8,1))
@@ -27,7 +26,7 @@ def run(exchange, compressor):
                   lr=0.05, worker_axes=("pod","data"))
     tr = DQGAN(field_fn=field, dq=dq, mesh=mesh, param_specs=pspecs,
                batch_spec=P(("pod","data")))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(tr.step)
         for i in range(25):
@@ -55,12 +54,11 @@ def test_delta1_equivalence_and_strategies(multidevice):
 
 EXCHANGE_SEMANTICS_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh, shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core import compressors as C
 from repro.core import exchange as X
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 W = 8
 comp = C.get("qsgd8_linf")
 shape = (16, 32)
@@ -84,9 +82,9 @@ def worker(p, key):
                            ("data",), W, True)
     return q[None]
 
-f = shard_map(worker, mesh=mesh, in_specs=(P("data"), P()),
-              out_specs=P("data"), axis_names=("data",))
-with set_mesh(mesh):
+f = jax.shard_map(worker, mesh=mesh, in_specs=(P("data"), P()),
+                  out_specs=P("data"), axis_names={"data"}, check_vma=False)
+with jax.set_mesh(mesh):
     q = f(ps, key)
 np.testing.assert_allclose(np.asarray(q[0]), np.asarray(ref_mean("allgather")),
                            rtol=1e-5, atol=1e-5)
@@ -106,9 +104,9 @@ def worker2(p, key):
                            ("data",), W, True)
     return q[None]
 
-f2 = shard_map(worker2, mesh=mesh, in_specs=(P("data"), P()),
-               out_specs=P("data"), axis_names=("data",))
-with set_mesh(mesh):
+f2 = jax.shard_map(worker2, mesh=mesh, in_specs=(P("data"), P()),
+                   out_specs=P("data"), axis_names={"data"}, check_vma=False)
+with jax.set_mesh(mesh):
     q2 = f2(ps, key)
 np.testing.assert_allclose(np.asarray(q2[0]), np.asarray(jnp.mean(ps, 0)),
                            rtol=1e-5, atol=1e-6)
@@ -123,8 +121,7 @@ def test_exchange_semantics(multidevice):
 
 SHARDED_TRAIN_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 import repro.configs as cfgs
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
@@ -133,7 +130,7 @@ from repro.parallel import sharding as shd
 from repro.data import synthetic_lm_batch
 
 # real (reduced) model trained data-parallel x tensor-parallel on 8 devices
-mesh = make_mesh((2,2,2), ("pod","data","model"))
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,) * 3)
 cfg = cfgs.get("gemma-2b").reduced()
 bundle = build(cfg)
 key = jax.random.key(0)
@@ -145,7 +142,7 @@ dq = DQConfig(optimizer="oadam", compressor="qsgd8_linf", exchange="two_phase",
               message="grad", lr=3e-3, worker_axes=("pod","data"))
 tr = DQGAN(field_fn=bundle.field_fn, dq=dq, mesh=mesh, param_specs=pspecs,
            batch_spec=P(("pod","data")))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = tr.init(params)
     step = jax.jit(tr.step, donate_argnums=0)
     losses = []
@@ -168,8 +165,7 @@ def test_sharded_model_training(multidevice):
 
 FSDP_LOWER_SCRIPT = r"""
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh, shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 import repro.configs as cfgs
 from repro.configs.base import DQConfig, InputShape
 from repro.core.dqgan import DQGAN
@@ -178,10 +174,10 @@ from repro.models import build
 from jax.sharding import NamedSharding
 
 # mode B: FSDP over 'data' + TP over 'model', DQGAN workers = pods.
-mesh = make_mesh((2,2,2), ("pod","data","model"))
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,) * 3)
 cfg = cfgs.get("qwen3-moe-30b-a3b").reduced()
 bundle = build(cfg)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     params_sds, pspecs = S.abstract_params(cfg, mesh, "fsdp", 8)
     # shard_map manual-over-pod + FSDP auto axes trips an XLA partitioner
     # CHECK (DESIGN.md §2) -> the vmap worker formulation is used instead.
@@ -201,4 +197,52 @@ with set_mesh(mesh):
 
 def test_fsdp_moe_lowering(multidevice):
     out = multidevice(FSDP_LOWER_SCRIPT)
+    assert "OK" in out
+
+
+INIT_PLACEMENT_SCRIPT = r"""
+import jax, jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
+from repro.configs.base import DQConfig
+from repro.core.dqgan import DQGAN
+from repro.models.gan import GANConfig, gan_field_fn, mlp_gan_init
+from repro.strategy import Compression, ExchangePlan, Strategy
+
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
+cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
+                hidden=128)
+params = mlp_gan_init(jax.random.key(0), cfg)
+strat = Strategy(compression=Compression(plan="uniform", bucket_mb=0.03),
+                 exchange=ExchangePlan(kind="two_phase",
+                                       worker_axes=("data",)))
+tr = DQGAN(field_fn=gan_field_fn(cfg),
+           dq=DQConfig.from_strategy(strat, optimizer="omd", lr=1e-2),
+           mesh=mesh, batch_spec=P(("data",)))
+traces = []
+
+def counting(st, batch, key, do_exchange):
+    traces.append(1)
+    return tr.step(st, batch, key, do_exchange)
+
+batch = {"real": jax.random.normal(jax.random.key(1), (64, 2))}
+with jax.set_mesh(mesh):
+    st0 = tr.init(params)
+    step = jax.jit(counting, static_argnums=(3,))
+    st1 = step(st0, batch, jax.random.key(7), True).state
+    st2 = step(st1, batch, jax.random.key(7), True).state
+assert len(traces) == 1, f"{len(traces)} traces"
+shard0 = [x.sharding for x in jax.tree.leaves(st0)]
+assert shard0 == [x.sharding for x in jax.tree.leaves(st1)]
+# per-worker slots: one worker's shard on each device
+for x in jax.tree.leaves((st0.prev_grad, st0.ef)):
+    assert len({s.device for s in x.addressable_shards}) == 8
+    assert all(s.data.shape[0] == 1 for s in x.addressable_shards)
+print("OK")
+"""
+
+
+def test_init_placement_one_trace(multidevice):
+    """`DQGAN.init` places every leaf as the step returns it: two steps
+    trace once, and init's shardings equal the step output's."""
+    out = multidevice(INIT_PLACEMENT_SCRIPT)
     assert "OK" in out

@@ -209,12 +209,10 @@ def test_fsdp_state_slots():
 # --------------------------------------------------------------------------- #
 FSDP_EQUIV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
-from repro.core import exchange as X
-from repro.obs.hlo import assert_fsdp_structure, check_fsdp_structure
+from repro.obs.hlo import assert_fsdp_structure
 from repro.strategy import (Strategy, Compression, ExchangePlan,
                             MomentCompression)
 
@@ -224,7 +222,7 @@ def field(params, batch, rng):
     s = 1.0 + jnp.mean(batch)
     return {"x": s * (A @ y), "y": -s * (A.T @ x)}, {"loss": x @ A @ y}
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 params = {"x": jnp.ones(64), "y": jnp.ones(64)}
 batch = jnp.arange(16, dtype=jnp.float32).reshape(16, 1) / 16.0
 traces = [0]
@@ -238,7 +236,7 @@ def run(st, steps=6, opt="adam", f=field, hlo=False):
     tr = DQGAN(field_fn=f, dq=dq, mesh=mesh,
                param_specs={"x": P(), "y": P()}, batch_spec=P(("data",)))
     sched = tr.strategy.schedule.runtime()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         txt = (step.lower(state, batch, jax.random.key(7), True)
@@ -278,22 +276,16 @@ p, txt = run(fsdp_q, steps=6, f=counting_field, hlo=True)
 assert all(np.isfinite(v).all() for v in p.values())
 assert traces[0] == 1, f"compressed fsdp retraced: {traces[0]} traces"
 print("TRACE-OK")
-if X._HAS_MODERN_SHARD_MAP:
-    assert_fsdp_structure(txt, compressed=True)
-    print("HLO-MODERN-OK")
-else:
-    # legacy emulation lowers psum_scatter to all-reduce + slice; the
-    # checker still parses the text (exercised, not asserted)
-    check_fsdp_structure(txt, compressed=True)
-    print("HLO-LEGACY-OK")
+assert_fsdp_structure(txt, compressed=True)
+print("HLO-OK")
 
 # a leaf sharded over a real (size>1) non-worker axis cannot enter a
 # flat bucket -> init fails fast naming the leaf
-mesh2 = make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 dq = DQConfig.from_strategy(fsdp_q, optimizer="adam", lr=0.05)
 tr = DQGAN(field_fn=field, dq=dq, mesh=mesh2,
            param_specs={"x": P("model"), "y": P()}, batch_spec=P(("data",)))
-with set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     try:
         tr.init(params)
     except ValueError as e:
@@ -306,14 +298,13 @@ print("ALL-OK")
 @pytest.mark.multidevice
 def test_fsdp_equivalence_8dev(multidevice):
     out = multidevice(FSDP_EQUIV_SCRIPT)
-    for tag in ("EQUIV-OK", "TRACE-OK", "SKIP-ERR-OK", "ALL-OK"):
+    for tag in ("EQUIV-OK", "TRACE-OK", "HLO-OK", "SKIP-ERR-OK", "ALL-OK"):
         assert tag in out, out
 
 
 FSDP_GAN_8DEV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models import gan
@@ -324,7 +315,7 @@ key = jax.random.key(0)
 cfg = gan.GANConfig(image_size=0, data_dim=2, hidden=32, latent_dim=8)
 params = gan.init(key, cfg)
 field = gan.gan_field_fn(cfg)
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 batch = {"real": jax.random.normal(key, (16, 2))}
 pspecs = jax.tree.map(lambda x: P(), params)
 
@@ -333,7 +324,7 @@ def run(st, steps=4):
     tr = DQGAN(field_fn=field, dq=dq, mesh=mesh, param_specs=pspecs,
                batch_spec=P(("data",)))
     sched = tr.strategy.schedule.runtime()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         for i in range(steps):
@@ -368,8 +359,7 @@ def test_fsdp_gan_equivalence_8dev(multidevice):
 
 FSDP_LM_8DEV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 import repro.configs as cfgs
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
@@ -383,7 +373,7 @@ cfg = cfgs.get("gemma-2b").reduced()
 bundle = build(cfg)
 params = bundle.init(key, max_seq=64)
 pspecs = jax.tree.map(lambda x: P(), params)
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 batch = synthetic_lm_batch(key, 8, 32, cfg.vocab_size)
 
 def run(st, steps=3):
@@ -391,7 +381,7 @@ def run(st, steps=3):
     tr = DQGAN(field_fn=bundle.field_fn, dq=dq, mesh=mesh, param_specs=pspecs,
                batch_spec=P(("data",)))
     sched = tr.strategy.schedule.runtime()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         for i in range(steps):

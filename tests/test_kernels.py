@@ -75,3 +75,18 @@ def test_flash_attention_bf16():
                                np.asarray(ref.reshape(2, S, D), np.float32),
                                rtol=2e-2, atol=2e-2)
     assert out.dtype == jnp.bfloat16
+
+
+def test_resolve_interpret_by_platform(monkeypatch):
+    """One dispatch rule: interpret on cpu, compiled on tpu, an explicit
+    bool wins, and any other platform is an error (no silent fallback)."""
+    import repro.kernels as K
+
+    assert K.resolve_interpret() is True            # this suite runs on cpu
+    assert K.resolve_interpret(False) is False
+    monkeypatch.setattr(K.jax, "default_backend", lambda: "tpu")
+    assert K.resolve_interpret() is False
+    assert K.resolve_interpret(True) is True
+    monkeypatch.setattr(K.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        K.resolve_interpret()

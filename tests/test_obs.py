@@ -497,15 +497,14 @@ def test_train_launcher_writes_valid_sink(tmp_path):
 # --------------------------------------------------------------------------- #
 INVARIANCE_8DEV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
 from repro.strategy import (Compression, ExchangePlan, Observability,
                             Participation, Schedule, Strategy)
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                 hidden=128)
 key = jax.random.key(0)
@@ -528,7 +527,7 @@ def run(spmd, metrics):
     dq = DQConfig.from_strategy(strat, optimizer="omd", lr=1e-2)
     tr = DQGAN(field_fn=gan_field_fn(cfg), dq=dq, mesh=mesh,
                batch_spec=P(("data",)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(tr.step, static_argnums=(3,))
         st = tr.init(params)
         for i in range(6):

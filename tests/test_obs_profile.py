@@ -162,15 +162,14 @@ def test_profile_on_hlo_identical():
 
 PROFILE_HLO_8DEV_SCRIPT = r"""
 import jax
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
 from repro.strategy import (Compression, ExchangePlan, Observability,
                             Schedule, Strategy)
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                 hidden=128)
 key = jax.random.key(0)
@@ -188,7 +187,7 @@ def lower(spmd, profile):
     dq = DQConfig.from_strategy(strat, optimizer="omd", lr=1e-2)
     tr = DQGAN(field_fn=gan_field_fn(cfg), dq=dq, mesh=mesh,
                batch_spec=P(("data",)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         return jax.jit(tr.step, static_argnums=(3,)).lower(
             st, batch, key, True).as_text()

@@ -423,8 +423,7 @@ _COMMON_8DEV = r"""
 import gzip, os, re
 import jax
 import numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
@@ -433,7 +432,7 @@ from repro.strategy import (Compression, ExchangePlan, Observability,
 from repro.obs import hlo as ohlo
 
 FIX = %r
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                 hidden=128)
 params = mlp_gan_init(jax.random.key(0), cfg)
@@ -455,11 +454,11 @@ def build(schedule, overlap=False, kind="two_phase", plan="uniform",
 """ % FIX
 
 IDENTITY_8DEV_SCRIPT = _COMMON_8DEV + r"""
-# source_file/source_line are environment-dependent debug metadata;
-# everything else must be byte-identical to the committed fixtures
+# source locations are environment-dependent debug metadata (the
+# fixtures are written without them); everything else must be
+# byte-identical to the committed fixtures
 def canon(t):
-    t = re.sub(r'source_file="[^"]*"', 'source_file=X', t)
-    return re.sub(r"source_line=\d+", "source_line=N", t)
+    return ohlo.strip_locations(t)
 
 def fixture(name):
     with gzip.open(os.path.join(FIX, name), "rt") as fh:
@@ -469,7 +468,7 @@ for name, schedule in [("every_step", Schedule()),
                        ("local_k4", Schedule.local_k(4)),
                        ("delayed_tau4", Schedule.delayed(tau=4))]:
     tr = build(schedule)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         ex = ohlo.compiled_text(step, st, batch, jax.random.key(7), True)
@@ -485,7 +484,7 @@ for name, schedule in [("every_step", Schedule()),
 
 # fresh overlap=True lowering passes the structural check
 tr = build(Schedule.delayed(tau=4), overlap=True)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = tr.init(params)
     step = jax.jit(tr.step, static_argnums=(3,))
     ex = ohlo.compiled_text(step, st, batch, jax.random.key(7), True)
@@ -498,7 +497,7 @@ print("OK")
 
 BITEXACT_8DEV_SCRIPT = _COMMON_8DEV + r"""
 def run(tr, steps=5):
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         for i in range(steps):

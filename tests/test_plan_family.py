@@ -301,15 +301,14 @@ def test_list_presets_cli():
 # --------------------------------------------------------------------------- #
 ADAPTIVE_8DEV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
 from repro.strategy import (Compression, ExchangePlan, Participation,
                             Strategy)
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                 hidden=128)
 key = jax.random.key(0)
@@ -330,7 +329,7 @@ def run(tr, steps=5):
     def counting(st, batch, k, do_ex):
         traces.append(1)
         return tr.step(st, batch, k, do_ex)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(counting, static_argnums=(3,))
         for i in range(steps):
@@ -379,8 +378,7 @@ def test_adaptive_dispatch_8dev(multidevice):
 # strategy.to_json guard
 ADAPTIVE_RESUME_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np, tempfile, os
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro import checkpoint
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
@@ -388,7 +386,7 @@ from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
 from repro.strategy import (Compression, ExchangePlan, Participation,
                             Strategy)
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                 hidden=128)
 key = jax.random.key(0)
@@ -406,7 +404,7 @@ N = 3
 def batch(i):
     return {"real": jax.random.normal(jax.random.fold_in(key, i), (64, 2))}
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(tr.step, static_argnums=(3,))
     st = tr.init(params)
     for i in range(2 * N):
@@ -451,15 +449,14 @@ def test_adaptive_checkpoint_resume_8dev(multidevice):
 # heterogeneous τ_m on 8 workers: per-worker staleness metrics + resume
 TAU_VECTOR_8DEV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models.gan import GANConfig, mlp_gan_init, gan_field_fn
 from repro.sched import seeded_tau_vector
 from repro.strategy import ExchangePlan, Schedule, Strategy
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 cfg = GANConfig(name="mix", image_size=0, data_dim=2, latent_dim=16,
                 hidden=128)
 key = jax.random.key(0)
@@ -472,7 +469,7 @@ def run(schedule, steps=6):
     tr = DQGAN(field_fn=gan_field_fn(cfg),
                dq=DQConfig.from_strategy(st, optimizer="omd", lr=1e-2),
                mesh=mesh, batch_spec=P(("data",)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         for i in range(steps):

@@ -543,7 +543,7 @@ def test_benchmark_gate_rejects_tier_mismatch(tmp_path):
     doctored["steps"] = 256          # pretend the baseline was full-tier
     bad = tmp_path / "sched_full_baseline.json"
     bad.write_text(json.dumps(doctored))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     proc = subprocess.run(
@@ -616,8 +616,7 @@ def test_ledger_counts_rounds_not_steps():
 SCHED_EQUIV_SCRIPT = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro import sched as S
@@ -628,7 +627,7 @@ def field(params, batch, rng):
     s = 1.0 + jnp.mean(batch)           # worker-dependent data
     return {"x": s * (A @ y), "y": -s * (A.T @ x)}, {"loss": x @ A @ y}
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 params = {"x": jnp.ones(4), "y": jnp.ones(4)}
 pspecs = {"x": P(), "y": P()}
 batch = jnp.arange(8, dtype=jnp.float32).reshape(8, 1) / 8.0
@@ -637,7 +636,7 @@ def run(dq, steps=16):
     tr = DQGAN(field_fn=field, dq=dq, mesh=mesh, param_specs=pspecs,
                batch_spec=P(("data",)))
     sched = S.get(dq.schedule, dq.local_k)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         for i in range(steps):
@@ -707,8 +706,7 @@ def test_sched_equivalences_8dev(multidevice):
 PARTICIPATION_SCRIPT = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro import sched as S
@@ -719,7 +717,7 @@ def field(params, batch, rng):
     s = 1.0 + jnp.mean(batch)
     return {"x": s * (A @ y), "y": -s * (A.T @ x)}, {"loss": x @ A @ y}
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 params = {"x": jnp.ones(4), "y": jnp.ones(4)}
 batch = jnp.arange(8, dtype=jnp.float32).reshape(8, 1) / 8.0
 key = jax.random.key(7)
@@ -732,7 +730,7 @@ dq = DQConfig(optimizer="omd", compressor="identity", exchange="sim",
               participation=0.5)
 tr = DQGAN(field_fn=field, dq=dq, mesh=mesh,
            param_specs={"x": P(), "y": P()}, batch_spec=P(("data",)))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = tr.init(params)
     out = jax.jit(tr.step, static_argnums=(3,))(st, batch, key, True)
 st1 = jax.device_get(out.state)
@@ -772,8 +770,7 @@ def test_participation_semantics_8dev(multidevice):
 PARTICIPATION_TAU_SCRIPT = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro import sched as S
@@ -784,7 +781,7 @@ def field(params, batch, rng):
     s = 1.0 + jnp.mean(batch)
     return {"x": s * (A @ y), "y": -s * (A.T @ x)}, {"loss": x @ A @ y}
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 params = {"x": jnp.ones(4), "y": jnp.ones(4)}
 batch = jnp.arange(8, dtype=jnp.float32).reshape(8, 1) / 8.0
 key = jax.random.key(7)
@@ -796,7 +793,7 @@ dq = DQConfig(optimizer="omd", compressor="identity", exchange="sim",
               schedule="delayed", staleness_tau=tau, participation=0.5)
 tr = DQGAN(field_fn=field, dq=dq, mesh=mesh,
            param_specs={"x": P(), "y": P()}, batch_spec=P(("data",)))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = tr.init(params)
     step = jax.jit(tr.step, static_argnums=(3,))
     stale_maxes = []

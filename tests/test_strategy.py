@@ -206,8 +206,7 @@ def test_legacy_vs_strategy_training_bit_exact(legacy):
 
 STRATEGY_8DEV_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.strategy import Strategy
@@ -218,7 +217,7 @@ def field(params, batch, rng):
     s = 1.0 + jnp.mean(batch)
     return {"x": s * (A @ y), "y": -s * (A.T @ x)}, {"loss": x @ A @ y}
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 params = {"x": jnp.ones(4), "y": jnp.ones(4)}
 batch = jnp.arange(8, dtype=jnp.float32).reshape(8, 1) / 8.0
 
@@ -226,7 +225,7 @@ def run(dq, steps=12):
     tr = DQGAN(field_fn=field, dq=dq, mesh=mesh,
                param_specs={"x": P(), "y": P()}, batch_spec=P(("data",)))
     sched = tr.strategy.schedule.runtime()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = tr.init(params)
         step = jax.jit(tr.step, static_argnums=(3,))
         for i in range(steps):

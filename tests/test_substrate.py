@@ -214,3 +214,23 @@ def test_dcgan_shapes():
     assert imgs.shape == (4, 32, 32, 3)
     score = dcgan_discriminate(p["disc"], cfg, imgs)
     assert score.shape == (4,)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache goes to the fixed .jax_cache/ at the repo root."""
+    import os
+
+    from repro.launch import compile_cache as cc
+
+    updates = []
+    monkeypatch.setattr(cc.jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+    assert cc.enable_compile_cache() == "cache-from-env"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert cc.enable_compile_cache() == cc.CACHE_DIR
+    assert updates == [("jax_compilation_cache_dir", cc.CACHE_DIR)]
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.CACHE_DIR == os.path.join(repo, ".jax_cache")
