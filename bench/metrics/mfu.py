@@ -1,0 +1,16 @@
+"""mfu: the whole step's model FLOPs per second over the traced window, as
+a share of the chips' bf16 peak. FLOPs from the layer shapes
+(`flops.step_flops`: the field's forward and backward passes, no
+recomputed forward), steps and seconds from the traced window."""
+import flops
+
+
+def read(ctx):
+    steps, window_s = ctx["steps"], ctx["window_s"]
+    if not steps or window_s <= 0:
+        return None
+    t = ctx["traffic"]
+    per_step = flops.step_flops(ctx["config"]["gan_config"],
+                                t["batch_per_worker"], t["workers"])
+    peak = ctx["chips"] * ctx["peaks"]["bf16_tflops"] * 1e12
+    return 100.0 * per_step * steps / window_s / peak

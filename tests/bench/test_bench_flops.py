@@ -1,0 +1,125 @@
+"""The model-FLOP and kernel-byte functions against hand counts, against
+XLA's count of the compiled field, and the bucket layout against the
+program's planner."""
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import lax
+
+import benchkit
+
+import flops
+
+SMOKE = dict(json.load(open(os.path.join(
+    benchkit.BENCH, "configs", "dcgan32.json")))["gan_config"],
+    **benchkit.SMOKE_MODEL)
+
+
+def _valid_products(x_shape, transpose):
+    """Products that touch input and weight, counted by convolving ones:
+    each output sums its valid taps."""
+    x = jnp.ones(x_shape)
+    w = jnp.ones((4, 4, 1, 1))
+    dn = ("NHWC", "HWIO", "NHWC")
+    if transpose:
+        y = lax.conv_transpose(x, w, (2, 2), "SAME", dimension_numbers=dn)
+    else:
+        y = lax.conv_general_dilated(x, w, (2, 2), "SAME",
+                                     dimension_numbers=dn)
+    return int(y.sum())
+
+
+@pytest.mark.parametrize("h", [2, 4, 8, 16, 32])
+def test_taps_by_convolving_ones(h):
+    assert flops.conv_taps(h) ** 2 == _valid_products((1, h, h, 1), False)
+    assert flops.conv_t_taps(h) ** 2 == _valid_products((1, h, h, 1), True)
+
+
+def test_smoke_hand_count():
+    # 8x8x3 images, latent 16, base width 8, s0 = 1.
+    # taps per axis, counted by hand: conv over 8 -> 14, over 4 -> 6,
+    # over 2 -> 2; conv_transpose of 1 -> 2, of 2 -> 6, of 4 -> 14.
+    assert [flops.conv_taps(h) for h in (8, 4, 2)] == [14, 6, 2]
+    assert [flops.conv_t_taps(h) for h in (1, 2, 4)] == [2, 6, 14]
+    gen, disc = flops.dcgan_layer_macs(SMOKE)
+    assert gen == [16 * 32, 2**2 * 32 * 16, 6**2 * 16 * 8, 14**2 * 8 * 3]
+    assert disc == [14**2 * 3 * 8, 6**2 * 8 * 16, 2**2 * 16 * 32, 32]
+    g, d = sum(gen), sum(disc)
+    # L_G: G fwd, D fwd, D dX (all), G dW + dX (not the first layer);
+    # L_D: D fwd on reals, then dW + dX (not the first) on reals and fakes
+    macs = (g + d + d + g + (g - gen[0])
+            + d + 2 * (d + d - disc[0]))
+    assert flops.field_flops_per_image(SMOKE) == 2 * macs
+    assert flops.step_flops(SMOKE, 8, 4) == 2 * macs * 32
+
+
+def test_field_against_compiled_count():
+    """The model count leaves out only elementwise work: within 2% of
+    XLA's count of the compiled dcgan32 field at batch 64."""
+    from repro.models.gan import GANConfig, gan_field_fn, init
+
+    gc = json.load(open(os.path.join(benchkit.BENCH, "configs",
+                                     "dcgan32.json")))["gan_config"]
+    cfg = GANConfig(**gc)
+    field = gan_field_fn(cfg)
+    params = jax.eval_shape(lambda k: init(k, cfg), jax.random.key(0))
+    real = jax.ShapeDtypeStruct((64, 32, 32, 3), jnp.float32)
+    compiled = jax.jit(lambda p, r, k: field(p, {"real": r}, k)).lower(
+        params, real, jax.random.key(0)).compile()
+    xla = compiled.cost_analysis()["flops"]
+    model = flops.step_flops(gc, 64, 1)
+    assert model <= xla and (xla - model) / xla < 0.02
+
+
+PEAKS = {"hbm_gbps": 819.0, "vmem_read_gbps": 18432.0,
+         "vmem_write_gbps": 6144.0}
+
+
+def test_kernel_bytes_hand_count():
+    # dcgan32's first bucket on one chip: 648 rows of 1024. Reads g, e and
+    # the uniforms (4 B each), writes int8 codes, one f32 scale per row and
+    # the f32 residual.
+    n = 648 * 1024
+    assert flops.quantize_kernel_arrays(648, 1024) == (
+        [4 * n, 4 * n, 4 * n], [n, 648 * 4, 4 * n])
+    # every array in VMEM, as XLA placed dcgan32's buckets: the stores
+    # bound it
+    t, bound = flops.least_seconds(648, 1024, [1, 1, 1], [1, 1, 1], PEAKS)
+    assert bound == "vmem_store"
+    assert t == pytest.approx((5 * n + 648 * 4) / 6144e9)
+    # every array in HBM: HBM bounds it
+    t, bound = flops.least_seconds(648, 1024, [0, 0, 0], [0, 0, 0], PEAKS)
+    assert bound == "hbm"
+    assert t == pytest.approx((n * 17 + 648 * 4) / 819e9)
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("config", ["dcgan32", "dcgan32-dim128"])
+def test_buckets_match_the_planner(config, workers):
+    from repro.comm import build_layout
+    from repro.models.gan import GANConfig, init
+
+    gc = json.load(open(os.path.join(benchkit.BENCH, "configs",
+                                     config + ".json")))["gan_config"]
+    shapes = jax.tree.map(
+        lambda x: tuple(x.shape),
+        jax.eval_shape(lambda k: init(k, GANConfig(**gc)),
+                       jax.random.key(0)))
+    layout = build_layout(shapes, None, workers)
+    ours = flops.bucket_layout(flops.dcgan_param_sizes(gc), workers)
+    assert [b.size for b in layout.buckets] == [s for s, _ in ours]
+    assert [[s.index for s in b.slots] for b in layout.buckets] == \
+        [m for _, m in ours]
+    if config == "dcgan32":
+        # rows of 1024 of each kernel call, as seen in the step compiled for
+        # a TPU v5e: the whole bucket on one chip, each owner's quarter of
+        # it in two_phase on four
+        assert [s // workers // 1024 for s, _ in ours] == (
+            [648, 648, 512] if workers == 1 else [162, 162, 128])
+    assert sum(math.prod(s) for s in jax.tree.leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))) == \
+        flops.n_params(gc)
