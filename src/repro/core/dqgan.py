@@ -661,34 +661,36 @@ class DQGAN:
             kw = jax.random.fold_in(jax.random.fold_in(key, i), state.step)
             kf, kq = jax.random.split(kw)
             pending_buf, pending = sched_c.wire_head(sw, i)
-            stale = sched_c.staleness_correction(pending_buf, dq.message,
-                                                 eta, i)
-            if dq.optimizer == "omd" and dq.extrapolation == "local":
-                def extrap(w, g_prev, e, s):
-                    upd = eta * g_prev
-                    if e is not None:
-                        upd = upd + e["e1"].astype(upd.dtype)
-                    if s is not None:
-                        upd = upd + s.astype(upd.dtype)
-                    return w - upd.astype(w.dtype)
-                leaves_p, tdp = jax.tree.flatten(state.params)
-                gl = tdp.flatten_up_to(prev_g)
-                el = (tdp.flatten_up_to(ef) if dq.error_feedback and ef
-                      is not None else [None] * len(leaves_p))
-                sl = (tdp.flatten_up_to(stale) if stale is not None
-                      else [None] * len(leaves_p))
-                w_half = jax.tree.unflatten(
-                    tdp, [extrap(w, g, e, s)
-                          for w, g, e, s in zip(leaves_p, gl, el, sl)])
-            elif dq.optimizer == "omd":
-                upd_tree = state.prev_update
-                if stale is not None:
-                    upd_tree = jax.tree.map(
-                        lambda u, s: u + s.astype(u.dtype), upd_tree, stale)
-                w_half = jax.tree.map(lambda w, u: w - u.astype(w.dtype),
-                                      state.params, upd_tree)
-            else:
-                w_half = state.params
+            with OBS.device_span("lookahead", spans):
+                stale = sched_c.staleness_correction(pending_buf, dq.message,
+                                                     eta, i)
+                if dq.optimizer == "omd" and dq.extrapolation == "local":
+                    def extrap(w, g_prev, e, s):
+                        upd = eta * g_prev
+                        if e is not None:
+                            upd = upd + e["e1"].astype(upd.dtype)
+                        if s is not None:
+                            upd = upd + s.astype(upd.dtype)
+                        return w - upd.astype(w.dtype)
+                    leaves_p, tdp = jax.tree.flatten(state.params)
+                    gl = tdp.flatten_up_to(prev_g)
+                    el = (tdp.flatten_up_to(ef) if dq.error_feedback and ef
+                          is not None else [None] * len(leaves_p))
+                    sl = (tdp.flatten_up_to(stale) if stale is not None
+                          else [None] * len(leaves_p))
+                    w_half = jax.tree.unflatten(
+                        tdp, [extrap(w, g, e, s)
+                              for w, g, e, s in zip(leaves_p, gl, el, sl)])
+                elif dq.optimizer == "omd":
+                    upd_tree = state.prev_update
+                    if stale is not None:
+                        upd_tree = jax.tree.map(
+                            lambda u, s: u + s.astype(u.dtype), upd_tree,
+                            stale)
+                    w_half = jax.tree.map(lambda w, u: w - u.astype(w.dtype),
+                                          state.params, upd_tree)
+                else:
+                    w_half = state.params
             with OBS.device_span("field", spans):
                 grads, metrics = self.field_fn(w_half, b, kf)
             if dq.message == "update" and dq.optimizer == "omd":
@@ -883,41 +885,44 @@ class DQGAN:
         # lookahead additionally subtracts the SUM of the worker's pending
         # (in-flight) messages as the staleness-correction proxy for the
         # τ outstanding q̂'s (DESIGN.md §8).
-        stale = sched_c.staleness_correction(pending_buf, dq.message, eta,
-                                             widx)
-        ef_leaf_tree = ef["leaf"] if (self.bucketed and ef is not None) else ef
-        if dq.optimizer == "omd":
-            if dq.extrapolation == "local":
-                e_term = ef_leaf_tree if dq.error_feedback else None
+        with OBS.device_span("lookahead", self._obs_spans):
+            stale = sched_c.staleness_correction(pending_buf, dq.message, eta,
+                                                 widx)
+            ef_leaf_tree = (ef["leaf"] if (self.bucketed and ef is not None)
+                            else ef)
+            if dq.optimizer == "omd":
+                if dq.extrapolation == "local":
+                    e_term = ef_leaf_tree if dq.error_feedback else None
 
-                def extrap(w, g_prev, e_leaf, s):
-                    upd = eta * g_prev
-                    if e_leaf is not None and "e1" in e_leaf:
-                        upd = upd + e_leaf["e1"].astype(w.dtype)
-                    if s is not None:
-                        upd = upd + s.astype(w.dtype)
-                    return w - upd.astype(w.dtype)
+                    def extrap(w, g_prev, e_leaf, s):
+                        upd = eta * g_prev
+                        if e_leaf is not None and "e1" in e_leaf:
+                            upd = upd + e_leaf["e1"].astype(w.dtype)
+                        if s is not None:
+                            upd = upd + s.astype(w.dtype)
+                        return w - upd.astype(w.dtype)
 
-                leaves_p, tdp = jax.tree.flatten(params)
-                gl = tdp.flatten_up_to(prev_grad)
-                el = (tdp.flatten_up_to(e_term) if e_term is not None
-                      else [None] * len(leaves_p))
-                sl = (tdp.flatten_up_to(stale) if stale is not None
-                      else [None] * len(leaves_p))
-                w_half = jax.tree.unflatten(
-                    tdp, [extrap(w, g, e, s)
-                          for w, g, e, s in zip(leaves_p, gl, el, sl)])
-            else:  # global: lookahead with the previously applied update
-                upd_tree = state.prev_update
-                if stale is not None:
-                    upd_tree = jax.tree.map(lambda u, s: u + s.astype(u.dtype),
-                                            upd_tree, stale)
-                w_half = jax.tree.map(
-                    lambda w, u: w - u.astype(w.dtype),
-                    params, upd_tree,
-                )
-        else:
-            w_half = params  # adam/oadam/sgd evaluate at current params
+                    leaves_p, tdp = jax.tree.flatten(params)
+                    gl = tdp.flatten_up_to(prev_grad)
+                    el = (tdp.flatten_up_to(e_term) if e_term is not None
+                          else [None] * len(leaves_p))
+                    sl = (tdp.flatten_up_to(stale) if stale is not None
+                          else [None] * len(leaves_p))
+                    w_half = jax.tree.unflatten(
+                        tdp, [extrap(w, g, e, s)
+                              for w, g, e, s in zip(leaves_p, gl, el, sl)])
+                else:  # global: lookahead with the previously applied update
+                    upd_tree = state.prev_update
+                    if stale is not None:
+                        upd_tree = jax.tree.map(
+                            lambda u, s: u + s.astype(u.dtype), upd_tree,
+                            stale)
+                    w_half = jax.tree.map(
+                        lambda w, u: w - u.astype(w.dtype),
+                        params, upd_tree,
+                    )
+            else:
+                w_half = params  # adam/oadam/sgd evaluate at current params
 
         # ---------- local stochastic field -------------------------------- #
         with OBS.device_span("field", self._obs_spans):
@@ -1136,7 +1141,8 @@ class DQGAN:
                 h = X.ExchangeHandle(pl["strategy"],
                                      lambda q=q1, ne=ne1: (q, ne))
             else:
-                h = exch_c.start(comp, pl, p, e, k, W, dq.error_feedback)
+                h = exch_c.start(comp, pl, p, e, k, W, dq.error_feedback,
+                                 spans=self._obs_spans)
             if eager:
                 q, ne = exch_c.finish(h)
                 if col.enabled:
@@ -1230,10 +1236,11 @@ class DQGAN:
 
         if plan["strategy"] == "exact" or comp.name == "identity":
             return p, dict(e)
-        e1 = e.get("e1", jnp.zeros_like(p))
-        _, p_hat, e_new = compress_with_ef(
-            comp, p, e1, key, use_ef=self.dq.error_feedback
-        )
+        with OBS.device_span("compress", self._obs_spans):
+            e1 = e.get("e1", jnp.zeros_like(p))
+            _, p_hat, e_new = compress_with_ef(
+                comp, p, e1, key, use_ef=self.dq.error_feedback
+            )
         ne = dict(e)
         if self.dq.error_feedback:
             ne["e1"] = e_new
@@ -1291,14 +1298,16 @@ class DQGAN:
                          for e in treedef.flatten_up_to(leaf_ef)]
 
         # ---- buckets: start = compress + wire collectives ----------------- #
-        flats = B.pack(layout, leaves)
-        e1_flats = None
-        if dq.error_feedback:
-            e1_leaves = [
-                e.get("e1", jnp.zeros(l.shape, ef_dtype))
-                for l, e in zip(leaves, ef_leaves)
-            ]
-            e1_flats = B.pack(layout, e1_leaves)
+        spans = self._obs_spans
+        with OBS.device_span("pack", spans):
+            flats = B.pack(layout, leaves)
+            e1_flats = None
+            if dq.error_feedback:
+                e1_leaves = [
+                    e.get("e1", jnp.zeros(l.shape, ef_dtype))
+                    for l, e in zip(leaves, ef_leaves)
+                ]
+                e1_flats = B.pack(layout, e1_leaves)
 
         out_flats, new_e1_flats, new_bucket_ef = [], [], {}
 
@@ -1336,7 +1345,7 @@ class DQGAN:
                                      lambda q=q1, ne=ne1: (q, ne))
             else:
                 h = exch_c.start(comp_b, plan_b, flats[b.bid], est, k, W,
-                                 dq.error_feedback)
+                                 dq.error_feedback, spans=spans)
             if eager:
                 finish_bucket(b, plan_b, est, h)
             else:
@@ -1355,7 +1364,7 @@ class DQGAN:
                                         lambda q=q1, ne=ne1: (q, ne))
             return exch_c.start(
                 base_comp, plan_leaves[s.index], leaves[s.index],
-                ef_leaves[s.index], k, W, dq.error_feedback)
+                ef_leaves[s.index], k, W, dq.error_feedback, spans=spans)
 
         skipped_started = []
         if not eager:
@@ -1364,10 +1373,11 @@ class DQGAN:
         def finish():
             for item in started:  # lazy: buckets' local post-processing
                 finish_bucket(*item)
-            out_leaves = B.unpack_into(layout, out_flats, leaves)
-            if dq.error_feedback:
-                new_e1_leaves = B.unpack_into(layout, new_e1_flats,
-                                              e1_leaves)
+            with OBS.device_span("pack", spans):
+                out_leaves = B.unpack_into(layout, out_flats, leaves)
+                if dq.error_feedback:
+                    new_e1_leaves = B.unpack_into(layout, new_e1_flats,
+                                                  e1_leaves)
             skipped_new = {}
             # eager keeps the historical order: start+finish each skipped
             # leaf AFTER the bucket unpack, one leaf at a time
@@ -1459,7 +1469,8 @@ class DQGAN:
                 est["e1"] = e1_flats[b.bid]
             k = jax.random.fold_in(key, 100_000 + b.bid)
             h = exch_c.start_reduce_scatter(
-                comp_b, flats[b.bid], est, k, W, dq.error_feedback)
+                comp_b, flats[b.bid], est, k, W, dq.error_feedback,
+                spans=self._obs_spans)
             started.append((b, est, h, jax.random.fold_in(k, 1)))
 
         def finish():
@@ -1479,7 +1490,7 @@ class DQGAN:
                     age = jnp.zeros_like(ag_in)
                 h_ag = exch_c.start_all_gather_shard(
                     mom_comp, ag_in, age.astype(jnp.float32), kag, W,
-                    mom_c.error_feedback)
+                    mom_c.error_feedback, spans=self._obs_spans)
                 full, new_age = exch_c.finish(h_ag)
                 ent["age"] = new_age.astype(jnp.float32)
                 new_fb[str(b.bid)] = ent

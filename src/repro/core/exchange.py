@@ -58,6 +58,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.obs.tracing import device_span
+
 from . import compressors as C
 from .error_feedback import compress_with_ef
 
@@ -183,10 +185,13 @@ def start_exchange(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
+    spans: bool = False,
 ) -> ExchangeHandle:
     """Issue the wire collectives for one tensor; return a handle whose
     `finish_exchange` yields (q̂, new_ef_state). Runs under
-    shard_map(axes).
+    shard_map(axes). With `spans`, the local compress (EF add, quantizer,
+    decode, residual) runs under the `repro.obs/compress` scope and every
+    collective outside it.
 
     Split points per strategy (start | finish):
       exact     : pmean(p)                              | identity
@@ -203,16 +208,20 @@ def start_exchange(
         return _resolved(strategy, _mean_axes(p, axes), new_state)
 
     if strategy == "sim":
-        e1 = ef_state.get("e1", jnp.zeros_like(p))
-        payload, p_hat, e_new = compress_with_ef(compressor, p, e1, key, use_ef=use_ef)
+        with device_span("compress", spans):
+            e1 = ef_state.get("e1", jnp.zeros_like(p))
+            payload, p_hat, e_new = compress_with_ef(compressor, p, e1, key,
+                                                     use_ef=use_ef)
         del payload
         if use_ef:
             new_state["e1"] = e_new
         return _resolved(strategy, _mean_axes(p_hat, axes), new_state)
 
     if strategy == "allgather":
-        e1 = ef_state.get("e1", jnp.zeros_like(p))
-        payload, p_hat, e_new = compress_with_ef(compressor, p, e1, key, use_ef=use_ef)
+        with device_span("compress", spans):
+            e1 = ef_state.get("e1", jnp.zeros_like(p))
+            payload, p_hat, e_new = compress_with_ef(compressor, p, e1, key,
+                                                     use_ef=use_ef)
         if use_ef:
             new_state["e1"] = e_new
         gathered = jax.tree.map(
@@ -228,7 +237,7 @@ def start_exchange(
 
     if strategy == "two_phase":
         return _start_two_phase(compressor, plan, p, ef_state, new_state, key,
-                                axes, n_workers, use_ef)
+                                axes, n_workers, use_ef, spans)
 
     raise ValueError(f"unknown strategy {strategy!r}")
 
@@ -262,20 +271,24 @@ def exchange_leaf(
 
 
 def _start_two_phase(compressor, plan, p, ef_state, new_state, key, axes, W,
-                     use_ef) -> ExchangeHandle:
+                     use_ef, spans=False) -> ExchangeHandle:
     ax = plan["chunk_axis"]
     orig_shape = p.shape
     # ---- phase 1: worker-side compress + all-to-all ------------------------ #
-    e1 = ef_state.get("e1", jnp.zeros_like(p))
-    m = p + e1.astype(p.dtype) if use_ef else p
-    # split the chunk axis: (..., ax, ...) -> (W, ..., ax/W, ...)
-    x = jnp.moveaxis(m, ax, 0).reshape((W, orig_shape[ax] // W) + _rest(orig_shape, ax))
-    keys = jax.random.split(key, W + 1)
-    payload = jax.vmap(compressor.compress)(x, keys[:W])
-    x_hat = jax.vmap(lambda pl: compressor.decompress(pl, x.shape[1:], x.dtype))(payload)
-    if use_ef:
-        e_new = (x - x_hat).reshape((orig_shape[ax],) + _rest(orig_shape, ax))
-        new_state["e1"] = jnp.moveaxis(e_new, 0, ax).astype(e1.dtype)
+    with device_span("compress", spans):
+        e1 = ef_state.get("e1", jnp.zeros_like(p))
+        m = p + e1.astype(p.dtype) if use_ef else p
+        # split the chunk axis: (..., ax, ...) -> (W, ..., ax/W, ...)
+        x = jnp.moveaxis(m, ax, 0).reshape(
+            (W, orig_shape[ax] // W) + _rest(orig_shape, ax))
+        keys = jax.random.split(key, W + 1)
+        payload = jax.vmap(compressor.compress)(x, keys[:W])
+        x_hat = jax.vmap(lambda pl: compressor.decompress(
+            pl, x.shape[1:], x.dtype))(payload)
+        if use_ef:
+            e_new = (x - x_hat).reshape(
+                (orig_shape[ax],) + _rest(orig_shape, ax))
+            new_state["e1"] = jnp.moveaxis(e_new, 0, ax).astype(e1.dtype)
     # all-to-all: leading dim becomes the source-worker index, int8 on the wire
     moved = jax.tree.map(lambda c: _all_to_all(c, axes), payload)
     contrib = jax.vmap(
@@ -283,12 +296,14 @@ def _start_two_phase(compressor, plan, p, ef_state, new_state, key, axes, W,
     )(moved)
     chunk_mean = jnp.mean(contrib, axis=0)  # this worker's chunk of q̂
     # ---- phase 2: owner-side compress (+ owner EF) + all-gather ------------ #
-    e2 = ef_state["e2"].reshape(chunk_mean.shape)
-    payload2, chunk_hat, e2_new = compress_with_ef(
-        compressor, chunk_mean, e2, keys[W], use_ef=True
-    )
-    del chunk_hat
-    new_state["e2"] = e2_new.reshape(ef_state["e2"].shape).astype(ef_state["e2"].dtype)
+    with device_span("compress", spans):
+        e2 = ef_state["e2"].reshape(chunk_mean.shape)
+        payload2, chunk_hat, e2_new = compress_with_ef(
+            compressor, chunk_mean, e2, keys[W], use_ef=True
+        )
+        del chunk_hat
+        new_state["e2"] = e2_new.reshape(ef_state["e2"].shape).astype(
+            ef_state["e2"].dtype)
     gathered = jax.tree.map(lambda c: jax.lax.all_gather(c, axes), payload2)
 
     def _finish_two_phase():
@@ -319,6 +334,7 @@ def start_reduce_scatter(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
+    spans: bool = False,
 ) -> ExchangeHandle:
     """The fsdp gradient leg: (compressed) reduce-scatter of one flat,
     worker-divisible bucket (DESIGN.md §15.2). ``p`` is (d,) with
@@ -341,9 +357,10 @@ def start_reduce_scatter(
         # compressor roundtrip so W=1 matches the W>1 math per worker
         if kind == "exact":
             return _resolved(kind, p, new_state)
-        e1 = ef_state.get("e1", jnp.zeros_like(p))
-        payload, p_hat, e_new = compress_with_ef(
-            compressor, p, e1, key, use_ef=use_ef)
+        with device_span("compress", spans):
+            e1 = ef_state.get("e1", jnp.zeros_like(p))
+            payload, p_hat, e_new = compress_with_ef(
+                compressor, p, e1, key, use_ef=use_ef)
         del payload
         if use_ef:
             new_state["e1"] = e_new.astype(e1.dtype)
@@ -358,16 +375,17 @@ def start_reduce_scatter(
             f"fsdp reduce-scatter: kind must be 'exact' or 'two_phase', "
             f"got {kind!r}")
     chunk = p.shape[0] // W
-    e1 = ef_state.get("e1", jnp.zeros_like(p))
-    m = p + e1.astype(p.dtype) if use_ef else p
-    x = m.reshape(W, chunk)
-    keys = jax.random.split(key, W)
-    payload = jax.vmap(compressor.compress)(x, keys)
-    if use_ef:
-        x_hat = jax.vmap(
-            lambda pl: compressor.decompress(pl, (chunk,), x.dtype)
-        )(payload)
-        new_state["e1"] = (x - x_hat).reshape(-1).astype(e1.dtype)
+    with device_span("compress", spans):
+        e1 = ef_state.get("e1", jnp.zeros_like(p))
+        m = p + e1.astype(p.dtype) if use_ef else p
+        x = m.reshape(W, chunk)
+        keys = jax.random.split(key, W)
+        payload = jax.vmap(compressor.compress)(x, keys)
+        if use_ef:
+            x_hat = jax.vmap(
+                lambda pl: compressor.decompress(pl, (chunk,), x.dtype)
+            )(payload)
+            new_state["e1"] = (x - x_hat).reshape(-1).astype(e1.dtype)
     # int8 codes on the wire; leading dim becomes the source-worker index
     moved = jax.tree.map(lambda c: _all_to_all(c, axes), payload)
 
@@ -388,6 +406,7 @@ def start_all_gather_shard(
     axes: Tuple[str, ...],
     n_workers: int,
     use_ef: bool,
+    spans: bool = False,
 ) -> ExchangeHandle:
     """The fsdp return leg: (compressed) all-gather of one owner shard —
     the quantized optimizer-state/parameter exchange of arXiv 2004.14180
@@ -396,8 +415,9 @@ def start_all_gather_shard(
     W payloads, so the gathered flat bucket is identical on all replicas.
     Finishes to (full (W·chunk,) flat bucket, new owner residual)."""
     W = max(n_workers, 1)
-    payload, c_hat, e_new = compress_with_ef(
-        compressor, shard, ag_ef, key, use_ef=use_ef)
+    with device_span("compress", spans):
+        payload, c_hat, e_new = compress_with_ef(
+            compressor, shard, ag_ef, key, use_ef=use_ef)
     new_ef = e_new if use_ef else ag_ef
     if W <= 1 or not axes:
         def _finish_local():

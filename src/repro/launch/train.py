@@ -61,7 +61,9 @@ PATH`` writes the versioned JSONL event stream (run meta, log rows,
 synced step/interval timings, obs metrics, comm summaries) for
 ``python -m repro.obs report PATH``; the default sink renders log rows
 on stdout exactly as before. ``--obs-spans`` adds named profiler spans
-(compress/exchange/apply on device, data/step/eval on the host):
+(lookahead/field/exchange/pack/compress/apply on device; one
+StepTraceAnnotation per loop iteration, and data/dispatch/sync/eval
+inside it, on the host):
 
     ... --preset adaptive_budget --obs-metrics full --obs-sink run.jsonl
 
@@ -336,74 +338,75 @@ def run(argv=None) -> TrainRun:
     ctx = jax.set_mesh(mesh) if mesh is not None else nullcontext()
     with ctx:
         for i in range(start, args.steps):
-            with obs_api.host_span("data", obs_spans), \
-                    profiler.phase("data"):
-                batch = next(it)
-            do_exchange = sched.is_exchange_step(i)
-            # every step is timed against a device sync — an unsynced
-            # perf_counter delta only measures dispatch, so without this
-            # the reported step time was only meaningful on the handful
-            # of steps that happened to block (the old wall-series seed)
-            it_t0 = time.perf_counter()
-            with obs_api.host_span("step", obs_spans), \
-                    profiler.phase("step"):
-                out = step(state, batch, key, do_exchange)
+            with obs_api.step_span(i, obs_spans):
+                with profiler.phase("data", obs_spans):
+                    batch = next(it)
+                do_exchange = sched.is_exchange_step(i)
+                # every step is timed against a device sync: `dispatch` is
+                # the call that enqueues the step, `sync` the wait for its
+                # metrics; an unsynced perf_counter delta would only
+                # measure dispatch
+                it_t0 = time.perf_counter()
+                with profiler.phase("dispatch", obs_spans):
+                    out = step(state, batch, key, do_exchange)
                 state = out.state
-                jax.block_until_ready(out.metrics)
-            step_s = time.perf_counter() - it_t0
-            profiler.record_step(i, step_s, do_exchange)
-            interval_s += step_s
-            interval_n += 1
-            if wall_series is None and (do_exchange in warm_variants
-                                        or i == args.steps - 1):
-                # base compute time from the first step whose jit variant
-                # already compiled (holds across resumes too); feeds the
-                # simulated (straggler-aware) wall-clock series
-                times = sstrag.step_times(profile, W, args.steps, args.seed,
-                                          base=step_s)
-                wall_series = sclock.simulate(
-                    sched, times, t_ex, strat.participation.fraction,
-                    args.seed)["per_step_s"]
-                if i > start:  # backfill the steps already run
-                    ledger.tick(0, wall_s=float(wall_series[start:i].sum()))
-            warm_variants.add(do_exchange)
-            wall = float(wall_series[i]) if wall_series is not None else 0.0
-            ledger.tick(exchanged=do_exchange, wall_s=wall,
-                        participants=n_part)
-            if i % args.log_every == 0 or i == args.steps - 1:
-                with obs_api.host_span("eval", obs_spans), \
-                        profiler.phase("eval"):
-                    m = jax.device_get(out.metrics)
-                rec = {"step": i, "round": sched.round_index(i),
-                       **({"participants": n_part}
-                          if n_part is not None else {}),
-                       "loss": float(m["loss"]),
-                       "grad_norm": float(m["grad_norm"]),
-                       "error_norm": float(m["error_norm"]),
-                       **({"staleness_max": float(m["staleness_max"]),
-                           "staleness_mean": round(
-                               float(m["staleness_mean"]), 2)}
-                          if strat.schedule.kind == "delayed" else {}),
-                       "wire_mb_step": round(
-                           ledger.wire_bytes_per_step / 1e6, 3),
-                       "cum_wire_mb": round(
-                           ledger.cumulative_wire_bytes / 1e6, 2),
-                       "comm_ratio": round(ledger.compression_ratio, 2),
-                       "sim_clock_s": round(ledger.sim_clock_s, 3),
-                       "elapsed_s": round(time.time() - t0, 1)}
-                history.append(rec)
-                sink.emit("train_log", **rec)
-                sink.emit("timing", step=i, step_s=round(step_s, 6),
-                          interval_s=round(interval_s, 6),
-                          steps_in_interval=interval_n)
-                interval_s = 0.0
-                interval_n = 0
-                if "obs" in m:
-                    sink.emit("obs_metrics", step=i, **m["obs"])
-            if (args.checkpoint and args.checkpoint_every
-                    and (i + 1) % args.checkpoint_every == 0
-                    and i != args.steps - 1):
-                save_ckpt(args.checkpoint, state, i + 1)
+                with profiler.phase("sync", obs_spans):
+                    jax.block_until_ready(out.metrics)
+                step_s = time.perf_counter() - it_t0
+                profiler.record_step(i, step_s, do_exchange)
+                interval_s += step_s
+                interval_n += 1
+                if wall_series is None and (do_exchange in warm_variants
+                                            or i == args.steps - 1):
+                    # base compute time from the first step whose jit
+                    # variant already compiled (holds across resumes too);
+                    # feeds the simulated (straggler-aware) wall-clock series
+                    times = sstrag.step_times(profile, W, args.steps,
+                                              args.seed, base=step_s)
+                    wall_series = sclock.simulate(
+                        sched, times, t_ex, strat.participation.fraction,
+                        args.seed)["per_step_s"]
+                    if i > start:  # backfill the steps already run
+                        ledger.tick(0, wall_s=float(
+                            wall_series[start:i].sum()))
+                warm_variants.add(do_exchange)
+                wall = (float(wall_series[i]) if wall_series is not None
+                        else 0.0)
+                ledger.tick(exchanged=do_exchange, wall_s=wall,
+                            participants=n_part)
+                if i % args.log_every == 0 or i == args.steps - 1:
+                    with profiler.phase("eval", obs_spans):
+                        m = jax.device_get(out.metrics)
+                    rec = {"step": i, "round": sched.round_index(i),
+                           **({"participants": n_part}
+                              if n_part is not None else {}),
+                           "loss": float(m["loss"]),
+                           "grad_norm": float(m["grad_norm"]),
+                           "error_norm": float(m["error_norm"]),
+                           **({"staleness_max": float(m["staleness_max"]),
+                               "staleness_mean": round(
+                                   float(m["staleness_mean"]), 2)}
+                              if strat.schedule.kind == "delayed" else {}),
+                           "wire_mb_step": round(
+                               ledger.wire_bytes_per_step / 1e6, 3),
+                           "cum_wire_mb": round(
+                               ledger.cumulative_wire_bytes / 1e6, 2),
+                           "comm_ratio": round(ledger.compression_ratio, 2),
+                           "sim_clock_s": round(ledger.sim_clock_s, 3),
+                           "elapsed_s": round(time.time() - t0, 1)}
+                    history.append(rec)
+                    sink.emit("train_log", **rec)
+                    sink.emit("timing", step=i, step_s=round(step_s, 6),
+                              interval_s=round(interval_s, 6),
+                              steps_in_interval=interval_n)
+                    interval_s = 0.0
+                    interval_n = 0
+                    if "obs" in m:
+                        sink.emit("obs_metrics", step=i, **m["obs"])
+                if (args.checkpoint and args.checkpoint_every
+                        and (i + 1) % args.checkpoint_every == 0
+                        and i != args.steps - 1):
+                    save_ckpt(args.checkpoint, state, i + 1)
         if profiler.step_walls:
             # close the profiled window (still under the mesh context —
             # the re-lowering below needs it). With spans on, the

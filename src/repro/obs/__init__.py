@@ -56,4 +56,4 @@ from .profile import (  # noqa: F401
     StepProfiler,
     make_profiler,
 )
-from .tracing import device_span, host_span  # noqa: F401
+from .tracing import device_span, host_span, step_span  # noqa: F401

@@ -9,15 +9,18 @@
   not dispatch latency. The profiler keeps the whole window and reports
   mean/min/max/p50 (min ≈ the no-jitter compute+comm floor the
   calibration fit leans on).
-* **host phases** — `phase(name)` contexts accumulate wall time per
-  host-side phase, keyed by the same canonical span names `tracing`
-  uses (``data`` / ``step`` / ``eval``), so a profile event and a
-  captured profiler trace name phases identically.
+* **host phases** — `phase(name, spans)` is the launcher's one context
+  per host-side phase (``data`` / ``dispatch`` / ``sync`` / ``eval``):
+  it opens the phase's `tracing.host_span` when spans are on and, while
+  the window is open, accumulates its wall time, so a profile event and
+  a captured profiler trace time and name each phase at one boundary.
 * **device phases** — with spans on, the compiled step's optimized HLO
   carries ``repro.obs/<phase>`` scope names in op metadata;
   `launch.hlo_analysis.scope_costs` turns that into per-phase op counts
-  and result bytes (compress / exchange / apply), a device-side cost
-  attribution that needs no hardware profiler and runs on host CI.
+  and result bytes (lookahead / field / exchange / apply), a device-side
+  cost attribution that needs no hardware profiler and runs on host CI.
+  Nested scopes (``pack`` and ``compress`` inside ``exchange``) count
+  under the outer one.
 * **trace capture** — an optional ``jax.profiler.trace`` directory
   brackets the window for TensorBoard-grade attribution on real
   hardware.
@@ -31,10 +34,10 @@ the bit-exactness tests pin the HLO equal either way.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Dict, List, Optional
 
-from .tracing import DEVICE_PHASES, HOST_PHASES, PREFIX
+from .tracing import DEVICE_PHASES, HOST_PHASES, PREFIX, host_span
 
 DEFAULT_WINDOW = 32
 
@@ -85,7 +88,7 @@ def overlap_ratio(walls_on, walls_off, exchange_s: Optional[float] = None
 class StepProfiler:
     """Collects one profiled window of a training run.
 
-    Life cycle: the launcher calls ``phase(name)`` around its host
+    Life cycle: the launcher calls ``phase(name, spans)`` around its host
     phases and ``record_step(step, step_s, exchanged)`` once per step;
     after ``window`` recorded steps the profiler is `done` and further
     calls are no-ops. `emit(sink, hlo_text=...)` writes the window as a
@@ -112,22 +115,24 @@ class StepProfiler:
     def done(self) -> bool:
         return not self.active
 
-    def phase(self, name: str):
-        """Wall-time accumulation context for a host phase (canonical
-        names: tracing.HOST_PHASES), open only while the window is."""
+    def phase(self, name: str, spans: bool = False):
+        """A host phase (canonical names: tracing.HOST_PHASES): its
+        TraceAnnotation when `spans`, and its wall time while the window
+        is open."""
         if not self.active:
-            return nullcontext()
-        return self._timed(name)
+            return host_span(name, spans)
+        return self._timed(name, spans)
 
     @contextmanager
-    def _timed(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            rec = self.phase_s.setdefault(name, [0.0, 0])
-            rec[0] += time.perf_counter() - t0
-            rec[1] += 1
+    def _timed(self, name: str, spans: bool):
+        with host_span(name, spans):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                rec = self.phase_s.setdefault(name, [0.0, 0])
+                rec[0] += time.perf_counter() - t0
+                rec[1] += 1
 
     def record_step(self, step: int, step_s: float,
                     exchanged: bool = True) -> None:
@@ -210,8 +215,8 @@ class NullStepProfiler:
     done = True
     step_walls: List[float] = []
 
-    def phase(self, name: str):
-        return nullcontext()
+    def phase(self, name: str, spans: bool = False):
+        return host_span(name, spans)
 
     def record_step(self, step: int, step_s: float,
                     exchanged: bool = True) -> None:

@@ -318,23 +318,25 @@ class ExchangePlan:
         return self.parallelism == "fsdp"
 
     def start_reduce_scatter(self, compressor, p, ef_state: dict, key,
-                             n_workers: int, use_ef: bool):
+                             n_workers: int, use_ef: bool,
+                             spans: bool = False):
         """Issue the (compressed) reduce-scatter of one flat bucket over
         this plan's worker axes; the handle finishes to this worker's
         mean shard (DESIGN.md §15.2)."""
         from repro.core import exchange as X
         return X.start_reduce_scatter(
             compressor, self.kind, p, ef_state, key, self.worker_axes,
-            n_workers, use_ef)
+            n_workers, use_ef, spans=spans)
 
     def start_all_gather_shard(self, compressor, shard, ag_ef, key,
-                               n_workers: int, use_ef: bool):
+                               n_workers: int, use_ef: bool,
+                               spans: bool = False):
         """Issue the (compressed) all-gather of one owner shard; the
         handle finishes to (full flat bucket, new owner EF)."""
         from repro.core import exchange as X
         return X.start_all_gather_shard(
             compressor, shard, ag_ef, key, self.worker_axes, n_workers,
-            use_ef)
+            use_ef, spans=spans)
 
     # ---- split-phase surface (DESIGN.md §13) -------------------------- #
     @property
@@ -346,12 +348,14 @@ class ExchangePlan:
         return X.plan_has_owner_ef({"strategy": self.kind})
 
     def start(self, compressor, plan: dict, p, ef_state: dict, key,
-              n_workers: int, use_ef: bool):
+              n_workers: int, use_ef: bool, spans: bool = False):
         """Issue the wire collectives for one tensor under this plan's
-        worker axes; returns a `core.exchange.ExchangeHandle`."""
+        worker axes; returns a `core.exchange.ExchangeHandle`. `spans`
+        names the compress ops (`repro.obs/compress`)."""
         from repro.core import exchange as X
         return X.start_exchange(compressor, plan, p, ef_state, key,
-                                self.worker_axes, n_workers, use_ef)
+                                self.worker_axes, n_workers, use_ef,
+                                spans=spans)
 
     def finish(self, handle):
         """(q̂, new_ef_state) from a handle returned by `start`."""

@@ -1,6 +1,7 @@
 """Step profiler (repro.obs.profile, DESIGN.md §12.1): window semantics,
 event schema, launcher integration, and the bit-exactness contract —
 profiling on/off must not shift the compiled step by one op."""
+import glob
 import gzip
 import json
 import os
@@ -50,6 +51,40 @@ def test_phase_accumulates_only_while_active():
     assert p.phase_s["data"][0] > 0
 
 
+def test_phase_is_one_span_system(monkeypatch):
+    """One context per host phase: it opens the phase's TraceAnnotation
+    when spans are on and keeps its wall total while the window is open;
+    a step's TraceAnnotation is a StepTraceAnnotation numbered by step."""
+    opened = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            opened.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Annotation)
+    p = StepProfiler(window=1)
+    with obs.step_span(7, True):
+        with p.phase("dispatch", spans=True):
+            pass
+        with p.phase("sync"):             # spans off: timed, no span
+            pass
+    p.record_step(7, 1e-3)
+    with p.phase("sync", spans=True):     # window closed: span only
+        pass
+    with obs.step_span(8, False):
+        pass
+    assert opened == [("repro.obs/train", {"step_num": 7}),
+                      ("repro.obs/dispatch", {}), ("repro.obs/sync", {})]
+    assert p.phase_s["dispatch"][1] == 1 and p.phase_s["sync"][1] == 1
+
+
 def test_summary_payload():
     p = StepProfiler(window=4)
     for i, w in enumerate([3.0, 2e-3, 3e-3, 4e-3]):   # wall 0 = compile
@@ -92,7 +127,9 @@ def test_make_profiler_factory():
 
 def test_null_profiler_surface(tmp_path):
     p = NullStepProfiler()
-    with p.phase("step"):
+    with p.phase("dispatch"):
+        pass
+    with p.phase("sync", spans=True):
         pass
     p.record_step(0, 1e-3)
     assert p.done and not p.active and p.step_walls == []
@@ -221,12 +258,44 @@ def test_train_launcher_emits_profile_event(tmp_path):
     assert prof["step0"] == 0 and prof["n_steps"] == 4
     assert prof["exchange_steps"] == 4          # every_step schedule
     assert prof["step_s"]["min"] > 0
-    assert {"data", "step"} <= set(prof["host_phases"])
+    # one span system: each host phase is timed where its span opens, and
+    # the step splits into the enqueue (dispatch) and the wait (sync)
+    assert {"data", "dispatch", "sync"} <= set(prof["host_phases"])
+    assert "step" not in prof["host_phases"]
+    assert all(prof["host_phases"][k]["n"] == 4 for k in ("data",
+                                                           "dispatch",
+                                                           "sync"))
     # single-device sim path still lowers named scopes -> device phases
     assert prof.get("device_phases"), prof.keys()
     # the calibrate CLI consumes this file end-to-end
     from repro.obs import calibrate
     assert calibrate.main([path]) == 0
+
+
+def test_train_launcher_trace_has_a_step_per_iteration(tmp_path):
+    """--profile-trace-dir with spans: the captured trace holds one
+    repro.obs/train step annotation per traced iteration, each with one
+    dispatch and one sync inside it."""
+    from jax.profiler import ProfileData
+
+    from repro.launch import train
+
+    trace_dir = str(tmp_path / "trace")
+    train.main(["--arch", "dcgan32", "--smoke", "--steps", "6",
+                "--log-every", "100", "--profile-steps", "5",
+                "--profile-trace-dir", trace_dir, "--obs-spans"])
+    (xplane,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+    names = [e.name for plane in ProfileData.from_file(xplane).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.obs/")]
+    steps = names.count("repro.obs/train")
+    # the trace starts after the window's first step and stops inside its
+    # last one, so the three steps between are whole
+    assert steps >= 3
+    assert names.count("repro.obs/dispatch") >= steps
+    assert names.count("repro.obs/sync") >= steps
 
 
 def test_train_launcher_obs_profile_flag_defaults_window(tmp_path):

@@ -1,12 +1,16 @@
 """Compiles for a described TPU v5e, no chip attached: the Pallas kernels of
-the main path at real sizes, and one full-width dcgan32 training step.
+the main path at real sizes, and full-width dcgan32 training steps, one of
+them the benchmark's q8.b64 step with its named scopes on and off.
 
 These catch what interpret mode cannot (tiling and VMEM refusals, a
 primitive Mosaic does not lower) at no chip time. The topology is described
 inside a module fixture and nowhere else: only one process may load the TPU
 library, and every test worker imports every test file.
 """
+import argparse
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +22,7 @@ import repro.kernels.quantize as KQ
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.kernels.flash_attention import flash_attention
+from repro import strategy as strategy_api
 from repro.models import build
 from repro.strategy import Compression, Strategy
 
@@ -110,3 +115,95 @@ def test_dcgan32_bucketed_step_compiles(one_chip, monkeypatch):
     compiled = jax.jit(tr.step, static_argnums=(3,)).lower(
         state, batch, key, True).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --------------------------------------------------------------------------- #
+# the benchmark's q8.b64 step: where its named scopes land on the chip
+# --------------------------------------------------------------------------- #
+Q8_B64 = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench", "traffic", "q8.b64.json")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _mix_strategy(flags):
+    ap = argparse.ArgumentParser()
+    strategy_api.add_strategy_args(ap)
+    return strategy_api.strategy_from_args(ap.parse_args(flags),
+                                           worker_axes=())
+
+
+@pytest.fixture(scope="module")
+def q8_b64_hlo(one_chip):
+    """The full-width dcgan32 step as the benchmark's q8.b64 mix builds it
+    (its flags, OMD, the update message), compiled for one described chip
+    with the mix's --obs-spans (True) and without it (False)."""
+    with open(Q8_B64) as fh:
+        mix = json.load(fh)
+    real = KQ.resolve_interpret
+    KQ.resolve_interpret = lambda interpret=None: bool(interpret)
+    try:
+        cfg, bundle, params = _dcgan32()
+
+        def on_chip(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        texts = {}
+        for spans in (True, False):
+            flags = [f for f in mix["flags"] if spans or f != "--obs-spans"]
+            dq = DQConfig.from_strategy(_mix_strategy(flags),
+                                        optimizer="omd", lr=mix["lr"],
+                                        message="update")
+            tr = DQGAN(field_fn=bundle.field_fn, dq=dq)
+            state = jax.tree.map(on_chip, tr.init_abstract(params))
+            batch = {"real": on_chip(jax.ShapeDtypeStruct(
+                (mix["batch_per_worker"], cfg.image_size, cfg.image_size,
+                 cfg.channels), jnp.float32))}
+            key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+            texts[spans] = jax.jit(
+                tr.step, static_argnums=(3,), donate_argnums=(0,)).lower(
+                    state, batch, key, True).compile().as_text()
+    finally:
+        KQ.resolve_interpret = real
+    return texts
+
+
+def _instructions(hlo_text):
+    """(opcode-bearing line, op_name) of every instruction with metadata."""
+    for line in hlo_text.splitlines():
+        m = _OP_NAME.search(line)
+        if m and " = " in line:
+            yield line, m.group(1)
+
+
+def test_q8_b64_step_scopes(q8_b64_hlo):
+    """The quantize kernel and the exchange's uniform draws run under
+    repro.obs/compress, the bucket concatenates under repro.obs/pack, both
+    inside repro.obs/exchange; the OMD lookahead has its own scope."""
+    ops = list(_instructions(q8_b64_hlo[True]))
+    kernels = [n for line, n in ops if "tpu_custom_call" in line]
+    assert len(kernels) == 3        # one per bucket
+    assert all("repro.obs/exchange/repro.obs/compress/" in n
+               for n in kernels), kernels
+    draws = [n for _, n in ops
+             if "repro.obs/exchange" in n and "jit(_uniform)" in n]
+    assert draws and all("repro.obs/compress/" in n for n in draws)
+    packs = [n for line, n in ops if " concatenate(" in line
+             and "repro.obs/exchange" in n]
+    assert packs and all("repro.obs/exchange/repro.obs/pack/" in n
+                         for n in packs), packs
+    assert any("repro.obs/lookahead/" in n for _, n in ops)
+    assert any("repro.obs/apply/" in n for _, n in ops)
+
+
+def test_q8_b64_step_scopes_change_only_metadata(q8_b64_hlo):
+    """Spans on and off compile to the same program: equal once op
+    metadata is stripped and instruction names are numbered by first
+    use (a custom call takes its name from the innermost scope)."""
+    def canonical(text):
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        names = {}
+        return re.sub(r"%([^\s,(){}=]+)", lambda m: "%" + str(
+            names.setdefault(m.group(1), len(names))), text)
+
+    assert canonical(q8_b64_hlo[True]) == canonical(q8_b64_hlo[False])
+    assert "repro.obs/" not in q8_b64_hlo[False]
