@@ -7,13 +7,16 @@ on the holes of a strided transposed convolution left out:
   L_G:  G forward; D forward on the fakes; D input-gradients on the fakes
         (every layer, down to the image); G weight-gradients and G
         input-gradients (every layer but the first, whose input is z).
-  L_D:  D forward on the reals; D weight-gradients and input-gradients
-        (every layer but the first) on the reals and on the fakes.
+  L_D:  D forward on the reals; D input-gradients on the reals (every
+        layer but the first); D weight-gradients on the reals and on the
+        fakes.
 
-The forward passes that L_D repeats (G on z, D on the fakes) are
-recomputation and do not count; neither do elementwise operations, the
-optimizer, error feedback, the quantizer or the exchange. One FLOP is one
-multiply or one add: a multiply-accumulate is two.
+Each product the field needs is counted once. L_D's loss on the fakes is
+L_G's negated, so its input-gradients on the fakes are L_G's with the sign
+flipped: they are counted on L_G's side alone, as are the forward passes
+that L_D repeats (G on z, D on the fakes). Elementwise operations, the
+optimizer, error feedback, the quantizer and the exchange do not count. One
+FLOP is one multiply or one add: a multiply-accumulate is two.
 
 Bytes of the fused EF + int8 quantize kernel (`kernels/quantize.py`), per
 call over an (R, C) tile of a bucket: it reads the message, the residual
@@ -77,9 +80,9 @@ def field_flops_per_image(gc: dict) -> int:
     g_fwd, d_fwd = sum(gen), sum(disc)
     g_bwd = sum(gen) + sum(gen[1:])            # dW all, dX all but first
     d_dx_all = sum(disc)
-    d_bwd_params = sum(disc) + sum(disc[1:])   # dW all, dX all but first
+    d_dw, d_dx_inner = sum(disc), sum(disc[1:])  # dW all; dX all but first
     macs = (g_fwd + d_fwd + d_dx_all + g_bwd     # L_G
-            + d_fwd + 2 * d_bwd_params)          # L_D: reals, then fakes
+            + d_fwd + d_dx_inner + 2 * d_dw)     # L_D: reals; dW on fakes
     return 2 * macs
 
 

@@ -1,7 +1,8 @@
 """mfu: the whole step's model FLOPs per second over the traced window, as
 a share of the chips' bf16 peak. FLOPs from the layer shapes
-(`flops.step_flops`: the field's forward and backward passes, no
-recomputed forward), steps and seconds from the traced window."""
+(`flops.step_flops`: the field's forward and backward passes, each
+product the field needs counted once), steps and seconds from the traced
+window."""
 import flops
 
 

@@ -50,29 +50,97 @@ def test_smoke_hand_count():
     assert disc == [14**2 * 3 * 8, 6**2 * 8 * 16, 2**2 * 16 * 32, 32]
     g, d = sum(gen), sum(disc)
     # L_G: G fwd, D fwd, D dX (all), G dW + dX (not the first layer);
-    # L_D: D fwd on reals, then dW + dX (not the first) on reals and fakes
+    # L_D: D fwd on reals, dW + dX (not the first) on reals, dW on fakes
     macs = (g + d + d + g + (g - gen[0])
-            + d + 2 * (d + d - disc[0]))
+            + d + (d + d - disc[0]) + d)
     assert flops.field_flops_per_image(SMOKE) == 2 * macs
     assert flops.step_flops(SMOKE, 8, 4) == 2 * macs * 32
 
 
-def test_field_against_compiled_count():
-    """The model count leaves out only elementwise work: within 2% of
-    XLA's count of the compiled dcgan32 field at batch 64."""
-    from repro.models.gan import GANConfig, gan_field_fn, init
+def _single_backward_field(cfg):
+    """The WGAN field of `gan_field_fn` with the critic's backward pass on
+    the fakes run once: one VJP of D on the fakes with cotangent +1/B gives
+    L_D's weight-gradients there, and its input cotangent, negated, is
+    L_G's, fed to the generator's VJP."""
+    from repro.models.gan import dcgan_discriminate, dcgan_generate
+
+    def field(params, batch, rng):
+        real = batch["real"]
+        z = jax.random.normal(rng, (real.shape[0], cfg.latent_dim))
+        fake, gen_vjp = jax.vjp(lambda g: dcgan_generate(g, cfg, z),
+                                params["gen"])
+        d_fake, fake_vjp = jax.vjp(
+            lambda d, x: dcgan_discriminate(d, cfg, x), params["disc"], fake)
+        d_real, real_vjp = jax.vjp(
+            lambda d: dcgan_discriminate(d, cfg, real), params["disc"])
+        gd_fake, gx_fake = fake_vjp(jnp.full_like(d_fake, 1 / d_fake.size))
+        (gd_real,) = real_vjp(jnp.full_like(d_real, -1 / d_real.size))
+        (g_gen,) = gen_vjp(-gx_fake)
+        g_disc = jax.tree.map(lambda r, f: cfg.disc_grad_mult * (r + f),
+                              gd_real, gd_fake)
+        lg = -jnp.mean(d_fake)
+        ld = -jnp.mean(d_real) + jnp.mean(d_fake)
+        return {"gen": g_gen, "disc": g_disc}, {"loss": ld + lg,
+                                                "loss_g": lg, "loss_d": ld}
+
+    return field
+
+
+def _dcgan32():
+    from repro.models.gan import GANConfig
 
     gc = json.load(open(os.path.join(benchkit.BENCH, "configs",
                                      "dcgan32.json")))["gan_config"]
-    cfg = GANConfig(**gc)
-    field = gan_field_fn(cfg)
+    return gc, GANConfig(**gc)
+
+
+def _compiled_flops(field, cfg, batch):
+    from repro.models.gan import init
+
     params = jax.eval_shape(lambda k: init(k, cfg), jax.random.key(0))
-    real = jax.ShapeDtypeStruct((64, 32, 32, 3), jnp.float32)
+    real = jax.ShapeDtypeStruct(
+        (batch, cfg.image_size, cfg.image_size, cfg.channels), jnp.float32)
     compiled = jax.jit(lambda p, r, k: field(p, {"real": r}, k)).lower(
         params, real, jax.random.key(0)).compile()
-    xla = compiled.cost_analysis()["flops"]
+    return compiled.cost_analysis()["flops"]
+
+
+def test_field_against_compiled_count():
+    """The model count leaves out only elementwise work: within 2% of
+    XLA's count of a compiled dcgan32 field at batch 64 that runs each
+    product once, and no more than the program's own field does."""
+    from repro.models.gan import gan_field_fn
+
+    gc, cfg = _dcgan32()
     model = flops.step_flops(gc, 64, 1)
-    assert model <= xla and (xla - model) / xla < 0.02
+    once = _compiled_flops(_single_backward_field(cfg), cfg, 64)
+    assert model <= once and (once - model) / once < 0.02
+    assert model <= _compiled_flops(gan_field_fn(cfg), cfg, 64)
+
+
+def test_single_backward_field_is_the_programs():
+    """The field the count is held against computes the program's: the
+    same losses and gradients on seeded dcgan32 weights, to float32
+    rounding."""
+    from repro.models.gan import gan_field_fn, init
+
+    _, cfg = _dcgan32()
+    params = init(jax.random.key(3), cfg)
+    batch = {"real": jax.random.uniform(jax.random.key(4), (16, 32, 32, 3),
+                                        minval=-1.0, maxval=1.0)}
+    rng = jax.random.key(5)
+    g_ours, m_ours = jax.jit(_single_backward_field(cfg))(params, batch, rng)
+    g_prog, m_prog = jax.jit(gan_field_fn(cfg))(params, batch, rng)
+    for k in ("loss", "loss_g", "loss_d"):
+        assert abs(float(m_ours[k]) - float(m_prog[k])) <= \
+            1e-5 * abs(float(m_prog[k]))
+    ours, prog = jax.tree.leaves(g_ours), jax.tree.leaves(g_prog)
+    norms = [float(jnp.linalg.norm(p)) for p in prog]
+    median = sorted(norms)[len(norms) // 2]
+    assert median > 0
+    for a, b, n in zip(ours, prog, norms):
+        assert a.shape == b.shape
+        assert float(jnp.linalg.norm(a - b)) <= 1e-5 * max(n, median)
 
 
 PEAKS = {"hbm_gbps": 819.0, "vmem_read_gbps": 18432.0,
