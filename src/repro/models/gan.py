@@ -145,16 +145,6 @@ def mlp_discriminate(disc, cfg, x):
 # --------------------------------------------------------------------------- #
 # the min-max field (what DQGAN transports)
 # --------------------------------------------------------------------------- #
-def generate(params, cfg, z):
-    f = dcgan_generate if cfg.is_image else mlp_generate
-    return f(params["gen"], cfg, z)
-
-
-def discriminate(params, cfg, x):
-    f = dcgan_discriminate if cfg.is_image else mlp_discriminate
-    return f(params["disc"], cfg, x)
-
-
 def init(key, cfg: GANConfig, max_seq: int = 0):
     del max_seq
     return (dcgan_init if cfg.is_image else mlp_gan_init)(key, cfg)
@@ -162,33 +152,32 @@ def init(key, cfg: GANConfig, max_seq: int = 0):
 
 def gan_field_fn(cfg: GANConfig):
     """Returns field_fn(params, batch, rng) -> (grads, metrics) for DQGAN.
-    batch: {"real": real samples}."""
+    batch: {"real": real samples}.
 
-    def loss_g(gen_params, disc_params, z):
-        fake = generate({"gen": gen_params}, cfg, z) if False else (
-            (dcgan_generate if cfg.is_image else mlp_generate)(gen_params, cfg, z)
-        )
-        d = (dcgan_discriminate if cfg.is_image else mlp_discriminate)(
-            disc_params, cfg, fake)
-        return -jnp.mean(d)
-
-    def loss_d(disc_params, gen_params, real, z):
-        disc = dcgan_discriminate if cfg.is_image else mlp_discriminate
-        genf = dcgan_generate if cfg.is_image else mlp_generate
-        fake = jax.lax.stop_gradient(genf(gen_params, cfg, z))
-        return -jnp.mean(disc(disc_params, cfg, real)) + jnp.mean(
-            disc(disc_params, cfg, fake))
+    The critic's backward pass on the fakes runs once, shared by both
+    losses: its VJP seeded with +1/B gives L_D's weight-gradients there, and
+    its input cotangent, negated, is L_G's, fed to the generator's VJP. The
+    reals take weight-gradients only."""
+    gen_f = dcgan_generate if cfg.is_image else mlp_generate
+    disc_f = dcgan_discriminate if cfg.is_image else mlp_discriminate
 
     def field_fn(params, batch, rng):
         real = batch["real"]
         z = jax.random.normal(rng, (real.shape[0], cfg.latent_dim))
-        lg, g_gen = jax.value_and_grad(loss_g)(params["gen"], params["disc"], z)
-        ld, g_disc = jax.value_and_grad(loss_d)(params["disc"], params["gen"],
-                                                real, z)
-        grads = {"gen": g_gen,
-                 "disc": jax.tree.map(lambda x: cfg.disc_grad_mult * x,
-                                      g_disc)}
-        return grads, {"loss": ld + lg, "loss_g": lg, "loss_d": ld}
+        fake, gen_vjp = jax.vjp(lambda g: gen_f(g, cfg, z), params["gen"])
+        d_fake, fake_vjp = jax.vjp(lambda d, x: disc_f(d, cfg, x),
+                                   params["disc"], fake)
+        d_real, real_vjp = jax.vjp(lambda d: disc_f(d, cfg, real),
+                                   params["disc"])
+        gd_fake, gx_fake = fake_vjp(jnp.full_like(d_fake, 1 / d_fake.size))
+        (gd_real,) = real_vjp(jnp.full_like(d_real, -1 / d_real.size))
+        (g_gen,) = gen_vjp(-gx_fake)
+        g_disc = jax.tree.map(lambda r, f: cfg.disc_grad_mult * (r + f),
+                              gd_real, gd_fake)
+        lg = -jnp.mean(d_fake)
+        ld = -jnp.mean(d_real) + jnp.mean(d_fake)
+        return ({"gen": g_gen, "disc": g_disc},
+                {"loss": ld + lg, "loss_g": lg, "loss_d": ld})
 
     return field_fn
 
