@@ -32,23 +32,22 @@ CONTROLS = ("bfloat16", "float8_e4m3fn")
 
 
 def program_numbers(spec, seed):
-    from pool import make_pool
     from program import build_program
     from reference import run_reference
 
     import check
 
-    traffic, gcfg = spec["traffic"], spec["config"]["gan_config"]
+    traffic, cfg, fam = spec["traffic"], spec["config"], spec["family"]
     W = traffic["workers"]
-    prog = build_program(spec["config"], traffic, seed, spec["cell"]["chips"])
-    pool = make_pool(seed, traffic["pool_batches"], W * traffic["batch_per_worker"],
-                     gcfg["image_size"], gcfg["channels"])
+    prog = build_program(fam, cfg, traffic, seed, spec["cell"]["chips"])
+    pool = fam.make_pool(cfg, seed, traffic["pool_batches"],
+                         W * traffic["batch_per_worker"])
     with prog.context():
         snap = R.first_steps(prog, pool, spec, seed)
     del prog, pool
     gc.collect()
-    ref = run_reference(gcfg, traffic, seed, R.reference_reals(spec, seed), W,
-                        spec["config"]["matmul_precision"])
+    ref = run_reference(fam, cfg, traffic, seed,
+                        R.reference_batches(spec, seed), W)
     return check.readings(snap, ref), ref
 
 
@@ -56,9 +55,9 @@ def reference_numbers(spec, seed, ref, **kw):
     import check
     from reference import run_reference
 
-    traffic, gcfg = spec["traffic"], spec["config"]["gan_config"]
-    alt = run_reference(gcfg, traffic, seed, R.reference_reals(spec, seed),
-                        traffic["workers"], spec["config"]["matmul_precision"],
+    traffic = spec["traffic"]
+    alt = run_reference(spec["family"], spec["config"], traffic, seed,
+                        R.reference_batches(spec, seed), traffic["workers"],
                         **kw)
     return check.readings(alt, ref)
 
@@ -75,8 +74,8 @@ def main(argv=None):
                     help="the faults to read (default: every one the cell "
                     "can have; empty: none)")
     args = ap.parse_args(argv)
-    spec = R.resolve(R.ROOT, args.workload)
     R.use_compile_cache()
+    spec = R.resolve(R.ROOT, args.workload)
     dev = R.require_chip(spec["cell"]["chips"])
     import check
     from reference import FAULTS

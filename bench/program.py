@@ -1,7 +1,8 @@
 """The system under test, assembled as `repro.launch.train.run` assembles it.
 
 `train.run` only knows registered archs and runs a fixed number of steps, so
-the benchmark builds the same objects itself: the mesh (none on one chip, a
+the benchmark builds the same objects itself: the model config the
+configuration's family reads from its file, the mesh (none on one chip, a
 pure ("data",) mesh over every chip otherwise), the strategy from the
 launcher's own flags, `DQConfig.from_strategy` with OMD and the update
 message, `DQGAN`, `trainer.init`, and the step jitted with the launcher's
@@ -23,15 +24,9 @@ from repro import strategy as strategy_api
 from repro.configs.base import DQConfig
 from repro.core.dqgan import DQGAN
 from repro.models import build
-from repro.models.gan import GANConfig
 from repro.parallel import sharding as shd
 
 from pool import seed_key
-
-
-def gan_config(config: dict) -> GANConfig:
-    """The `GANConfig` a configuration file describes."""
-    return GANConfig(**config["gan_config"])
 
 
 def parse_strategy(flags, worker_axes):
@@ -45,7 +40,7 @@ def parse_strategy(flags, worker_axes):
 
 @dataclass
 class Program:
-    cfg: GANConfig
+    cfg: Any           # the model config `repro.models.build` takes
     trainer: DQGAN
     sched: Any
     step: Any          # jax.jit(trainer.step, static_argnums=(3,), donate=(0,))
@@ -75,9 +70,11 @@ class Program:
         return out.metrics, t1 - t0, t2 - t0
 
 
-def build_program(config: dict, traffic: dict, seed: int,
+def build_program(family, config: dict, traffic: dict, seed: int,
                   n_devices: int) -> Program:
-    cfg = gan_config(config)
+    """The launcher's step for the model that `family` reads from the
+    configuration file `config`, under the traffic mix `traffic`."""
+    cfg = family.program_config(config)
     bundle = build(cfg)
     worker_axes = ("data",) if n_devices > 1 else ()
     strat = parse_strategy(traffic["flags"], worker_axes)

@@ -4,20 +4,23 @@ Written from the algorithm, not from the program, in plain `jax.numpy` and
 `jax.lax`, at the matmul precision the configuration states:
 
   worker m:  w_half = w - (lr * g_prev^m + e1^m)          (OMD lookahead)
-             g^m    = F(w_half; reals^m, z^m)              (WGAN field)
+             g^m    = F(w_half; batch^m, noise^m)          (the field)
              p^m    = lr * g^m                             (the update message)
   exchange:  q = mean_m Q(p^m + e1^m), e1^m the residual   (error feedback)
              with two_phase: each worker's chunk of the mean is quantized
              again with the owner's residual e2 before it is gathered
   all:       w = w - q
 
-F is the WGAN field of the DCGAN: [grad of L_G = -E D(G(z)) in the
-generator, disc_grad_mult x grad of L_D = -E D(x) + E D(G(z)) in the
-critic]. Q is stochastic int8 quantization with one linf scale per 1024
-elements of the flat comm bucket; the fused kernel's rounding floor(m/s*127)
-+ [u < frac] on the worker side of one chip and on the owner side of
-two_phase, QSGD's sign-magnitude rounding on the worker side of two_phase.
-The exact exchange is a plain mean with no quantizer and no residual.
+F, its noise and the weights belong to the configuration's family
+(`bench/families/<family>.py`): `init_params`, `draw` (the noise of a
+worker's rows, such as a GAN's latent z), and `field`, which may carry a
+state of the family's own per worker that is neither trained nor
+exchanged (`init_field_state`). This module knows no model. Q is stochastic
+int8 quantization with one linf scale per 1024 elements of the flat comm
+bucket; the fused kernel's rounding floor(m/s*127) + [u < frac] on the
+worker side of one chip and on the owner side of two_phase, QSGD's
+sign-magnitude rounding on the worker side of two_phase. The exact exchange
+is a plain mean with no quantizer and no residual.
 
 The configurations state float32 at the default matmul precision
 (`matmul_precision` in the configuration file), which on a TPU multiplies
@@ -25,7 +28,7 @@ each convolution's and product's operands in one bfloat16 pass and
 accumulates in float32, forward and backward; everything else is float32.
 
 The reference draws what the algorithm draws from the seed as the algorithm
-defines it: the weights, the latent z of each worker and step, and the
+defines it: the weights, the noise of each worker and step, and the
 uniforms of each bucket's stochastic rounding. It takes no array from the
 program. Both start from the same optimistic term g_prev, which the
 algorithm leaves free (the program's own start is 0): `lookahead_probe`
@@ -38,8 +41,9 @@ comparison of three steps can tell from no lookahead at all.
 `control` computes the field in a lower precision: "bfloat16", the step
 below float32 at the default precision (products, activations and their
 cotangents in bfloat16), or "float8_e4m3fn" (the operands of the forward
-products rounded to float8 e4m3); `fault` plants one of the faults the
-comparison must catch (`FAULTS`).
+products rounded to float8 e4m3); families apply it to each product's
+operands with `operand`. `fault` plants one of the faults the comparison
+must catch (`FAULTS`).
 """
 from __future__ import annotations
 
@@ -56,12 +60,11 @@ from pool import seed_key
 
 LEVELS = 127
 BLOCK = 1024
-DN = ("NHWC", "HWIO", "NHWC")
 PROBE = 0.1          # step 1's lookahead displacement, over each leaf's rms
 PROBE_TAG = 0x0A4EAD
 
 FAULTS = (
-    "half_batch",    # half of each worker's batch, the mean over the rest
+    "half_batch",    # half of each worker's rows and noise, the mean over them
     "no_exchange",   # each worker applies its own update (several workers)
     "no_lookahead",  # the field at w, not at the OMD lookahead point
     "sign_flip",     # the apply adds the exchanged update: w + q
@@ -69,38 +72,13 @@ FAULTS = (
 
 
 # --------------------------------------------------------------------------- #
-# model
+# precision
 # --------------------------------------------------------------------------- #
-def init_params(gc: dict, key):
-    """DCGAN weights from the key: conv kernels N(0, 0.02), fc weights
-    N(0, 1/fan_in), biases 0, one split of the key per layer."""
-    bw, s0, ch, lat = (gc["base_width"], gc["image_size"] // 8,
-                       gc["channels"], gc["latent_dim"])
-    ks = jax.random.split(key, 10)
-
-    def conv(k, cin, cout):
-        return {"w": jax.random.normal(k, (4, 4, cin, cout)) * 0.02,
-                "b": jnp.zeros((cout,))}
-
-    def fc(k, din, dout):
-        return {"w": jax.random.normal(k, (din, dout), jnp.float32)
-                * (1.0 / math.sqrt(din)), "b": jnp.zeros((dout,))}
-
-    return {"gen": {"fc": fc(ks[0], lat, s0 * s0 * bw * 4),
-                    "c1": conv(ks[1], bw * 4, bw * 2),
-                    "c2": conv(ks[2], bw * 2, bw),
-                    "c3": conv(ks[3], bw, ch)},
-            "disc": {"c1": conv(ks[4], ch, bw),
-                     "c2": conv(ks[5], bw, bw * 2),
-                     "c3": conv(ks[6], bw * 2, bw * 4),
-                     "fc": fc(ks[7], s0 * s0 * bw * 4, 1)}}
-
-
 PRECISION = {"default": lax.Precision.DEFAULT,
              "highest": lax.Precision.HIGHEST}
 
 
-def _operand(x, control):
+def operand(x, control):
     """A forward convolution's or product's operand: as it is; for the
     bfloat16 control cast, so that the field's products, activations and
     their cotangents are bfloat16; for the float8 control rounded to e4m3
@@ -111,59 +89,6 @@ def _operand(x, control):
         return x.astype(jnp.bfloat16)
     return x + lax.stop_gradient(
         x.astype(jnp.float8_e4m3fn).astype(jnp.float32) - x)
-
-
-def generate(gen, gc, z, prec, control):
-    bw, s0 = gc["base_width"], gc["image_size"] // 8
-    p = jax.tree.map(lambda x: _operand(x, control), gen)
-    x = jnp.dot(_operand(z, control), p["fc"]["w"], precision=prec)
-    x = jax.nn.relu(x + p["fc"]["b"]).reshape(-1, s0, s0, bw * 4)
-    for name, act in (("c1", jax.nn.relu), ("c2", jax.nn.relu),
-                      ("c3", jnp.tanh)):
-        x = lax.conv_transpose(_operand(x, control), p[name]["w"], (2, 2),
-                               "SAME", dimension_numbers=DN, precision=prec)
-        x = act(x + p[name]["b"])
-    return x
-
-
-def discriminate(disc, gc, x, prec, control):
-    p = jax.tree.map(lambda a: _operand(a, control), disc)
-    h = x
-    for name in ("c1", "c2", "c3"):
-        h = lax.conv_general_dilated(_operand(h, control), p[name]["w"],
-                                     (2, 2), "SAME", dimension_numbers=DN,
-                                     precision=prec)
-        h = jax.nn.leaky_relu(h + p[name]["b"], 0.2)
-    h = h.reshape(h.shape[0], -1)
-    return (jnp.dot(_operand(h, control), p["fc"]["w"], precision=prec)
-            + p["fc"]["b"])[:, 0]
-
-
-def field(params, gc, real, z, prec, control):
-    """(field, loss = L_D + L_G, mean |D(real)|)."""
-    mult = gc["disc_grad_mult"]
-
-    def loss_g(gen):
-        d = discriminate(params["disc"], gc, generate(gen, gc, z, prec,
-                                                      control), prec, control)
-        return -jnp.mean(d.astype(jnp.float32))
-
-    def loss_d(disc):
-        fake = lax.stop_gradient(generate(params["gen"], gc, z, prec,
-                                          control))
-        d_real = discriminate(disc, gc, real, prec, control).astype(
-            jnp.float32)
-        d_fake = discriminate(disc, gc, fake, prec, control).astype(
-            jnp.float32)
-        return -jnp.mean(d_real) + jnp.mean(d_fake), jnp.mean(
-            jnp.abs(d_real))
-
-    lg, g_gen = jax.value_and_grad(loss_g)(params["gen"])
-    (ld, scale), g_disc = jax.value_and_grad(loss_d, has_aux=True)(
-        params["disc"])
-    grads = {"gen": g_gen,
-             "disc": jax.tree.map(lambda x: mult * x, g_disc)}
-    return grads, ld + lg, scale
 
 
 # --------------------------------------------------------------------------- #
@@ -243,29 +168,33 @@ def exchange_compressed(msgs, e1, e2, kqs, layout, shapes, two_phase):
 # --------------------------------------------------------------------------- #
 # the step
 # --------------------------------------------------------------------------- #
-def make_step(gc, traffic, W, prec, control=None, fault=None):
-    """step(state, reals (W, B, H, W, C), key, i) -> (state, loss, loss
-    scale, the field averaged over workers, the applied update), the last
-    two as flat leaf lists. Per-worker state leaves lead with the worker
-    axis."""
+def _rows(tree):
+    return jax.tree.leaves(tree)[0].shape[0]
+
+
+def make_step(fam, config, traffic, W, prec, control=None, fault=None):
+    """step(state, batches, key, i) -> (state, loss, loss scale, the field
+    averaged over workers, the applied update), the last two as flat leaf
+    lists. `batches` is a batch dict whose arrays lead with (W, B); per-worker
+    state leaves lead with the worker axis."""
     lr = traffic["lr"]
     if traffic["exchange"] not in ("single", "two_phase", "exact"):
         raise ValueError(f"no reference for exchange {traffic['exchange']!r}")
     compressed = traffic["exchange"] != "exact"
     two_phase = traffic["exchange"] == "two_phase"
-    lat = gc["latent_dim"]
 
-    def worker(params, prev, e1, real, kfield):
+    def worker(params, fstate, prev, e1, batch, kfield):
         half = jax.tree.map(lambda p, g, e: p - (lr * g + e), params, prev,
                             e1)
         if fault == "no_lookahead":
             half = params
-        z = jax.random.normal(kfield, (real.shape[0], lat))
+        noise = fam.draw(config, kfield, _rows(batch))
         if fault == "half_batch":
-            real, z = real[: real.shape[0] // 2], z[: real.shape[0] // 2]
-        return field(half, gc, real, z, prec, control)
+            batch, noise = jax.tree.map(lambda x: x[: x.shape[0] // 2],
+                                        (batch, noise))
+        return fam.field(half, fstate, config, batch, noise, prec, control)
 
-    def step(state, reals, key, i):
+    def step(state, batches, key, i):
         params = state["params"]
         leaves_w, treedef = jax.tree.flatten(params)
         shapes = [tuple(x.shape) for x in leaves_w]
@@ -275,9 +204,10 @@ def make_step(gc, traffic, W, prec, control=None, fault=None):
         kfield, kq = jax.vmap(
             lambda k: tuple(jax.random.split(jax.random.fold_in(k, i))))(kws)
         unflat = partial(jax.tree.unflatten, treedef)
-        g, losses, scales = jax.vmap(worker, in_axes=(None, 0, 0, 0, 0))(
-            params, unflat(state["prev_grad"]), unflat(state["e1"]), reals,
-            kfield)
+        g, losses, scales, fstate = jax.vmap(
+            worker, in_axes=(None, 0, 0, 0, 0, 0))(
+            params, state["field"], unflat(state["prev_grad"]),
+            unflat(state["e1"]), batches, kfield)
         g = jax.tree.leaves(g)
         msgs = [lr * x for x in g]
         e1, e2 = state["e1"], state["e2"]
@@ -298,7 +228,8 @@ def make_step(gc, traffic, W, prec, control=None, fault=None):
         sign = 1.0 if fault == "sign_flip" else -1.0
         new_state = {"params": unflat([p + sign * u
                                        for p, u in zip(leaves_w, q)]),
-                     "prev_grad": g, "e1": new_e1, "e2": new_e2}
+                     "prev_grad": g, "e1": new_e1, "e2": new_e2,
+                     "field": fstate}
         mean_field = [jnp.mean(x, axis=0) for x in g]
         return (new_state, jnp.mean(losses), jnp.mean(scales), mean_field,
                 q)
@@ -306,13 +237,13 @@ def make_step(gc, traffic, W, prec, control=None, fault=None):
     return step
 
 
-def lookahead_probe(gc, seed, W, lr):
+def lookahead_probe(fam, config, seed, W, lr):
     """The optimistic term g_prev both sides start from: per flat leaf,
     (W, *shape), so that lr * g_prev is PROBE times the leaf's rms at init
     in a seeded normal direction of each worker's own (0 on the biases,
     which start at 0). Made on the device in one jitted call."""
     def make(key):
-        w0 = jax.tree.leaves(init_params(gc, key))
+        w0 = jax.tree.leaves(fam.init_params(config, key))
         ks = jax.random.split(jax.random.fold_in(key, PROBE_TAG), len(w0))
         return [jax.random.normal(k, (W,) + x.shape)
                 * (PROBE / lr * jnp.sqrt(jnp.mean(x * x)))
@@ -321,36 +252,42 @@ def lookahead_probe(gc, seed, W, lr):
     return jax.jit(make)(seed_key(seed))
 
 
-def init_state(gc, key, W, prev_grad):
-    params = init_params(gc, key)
+def init_state(fam, config, key, W, prev_grad):
+    params = fam.init_params(config, key)
     leaves = jax.tree.leaves(params)
     layout = flops.bucket_layout([x.size for x in leaves], W)
     zeros = [jnp.zeros((W,) + x.shape) for x in leaves]
+    field = jax.tree.map(lambda x: jnp.broadcast_to(x, (W,) + x.shape),
+                         fam.init_field_state(config, key))
     return {"params": params, "prev_grad": list(prev_grad),
             "e1": list(zeros),
-            "e2": [jnp.zeros((W, size // W)) for size, _ in layout]}
+            "e2": [jnp.zeros((W, size // W)) for size, _ in layout],
+            "field": field}
 
 
-def run_reference(gc, traffic, seed, reals, W, precision, steps=3,
+def run_reference(fam, config, traffic, seed, batches, W, steps=3,
                   control=None, fault=None):
-    """The first `steps` steps from the seed on `reals` (steps, W, B, ...),
-    at the matmul `precision` the configuration states, from the seeded
-    optimistic term of `lookahead_probe`. Returns host
-    arrays: losses, loss scales, the per-leaf norms of the first field (mean
-    over workers), the first applied update, and the flat parameter leaves
-    before the first step and after the last."""
+    """The first `steps` steps of the family `fam`'s model under the
+    configuration `config` from the seed, on `batches` (a batch dict whose
+    arrays lead with (steps, W, B)), at the matmul precision the
+    configuration states, from the seeded optimistic term of
+    `lookahead_probe`. Returns host arrays: losses, loss scales, the
+    per-leaf norms of the first field (mean over workers), the first
+    applied update, and the flat parameter leaves before the first step
+    and after the last."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"no fault {fault!r}; have {FAULTS}")
     key = seed_key(seed)
-    step = jax.jit(make_step(gc, traffic, W, PRECISION[precision], control,
+    step = jax.jit(make_step(fam, config, traffic, W,
+                             PRECISION[config["matmul_precision"]], control,
                              fault))
-    state = init_state(gc, key, W,
-                       lookahead_probe(gc, seed, W, traffic["lr"]))
+    state = init_state(fam, config, key, W,
+                       lookahead_probe(fam, config, seed, W, traffic["lr"]))
     w0 = jax.device_get(jax.tree.leaves(state["params"]))
     losses, scales, g1, q1 = [], [], None, None
     for i in range(steps):
-        state, loss, scale, mean_field, q = step(state, reals[i], key,
-                                                 jnp.int32(i))
+        state, loss, scale, mean_field, q = step(
+            state, jax.tree.map(lambda x: x[i], batches), key, jnp.int32(i))
         losses.append(float(loss))
         scales.append(float(scale))
         if i == 0:

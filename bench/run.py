@@ -7,17 +7,23 @@ A cell is a configuration (`bench/configs/<config>.json`) under a traffic mix
 (`bench/traffic/<traffic>.json`), with the limits of its comparison in
 `bench/limits/<cell>.json`; the per-layer metrics are readers in
 `bench/metrics/<metric>.py`. All are found by the names in BENCHMARK.json.
+The configuration names its model family (`"family"`), a module in
+`bench/families/<family>.py` that gives everything model-specific: the
+program's model config, the data, the plain reference of the field, the
+model FLOPs and the parameter sizes. The rest of the harness knows no
+model.
 
 Set-up builds the training step as the launcher does (`program.py`), makes
-the pool of batches from the seed, sets the optimistic term the reference
-also starts from, and takes the first three steps through the window's own
-call, keeping the state after steps 0, 1 and 3 on the host for the
-comparison. It warms up, then measures for --seconds seconds: each
-step is the launcher's loop body, synced on its metrics. With --trace 1 it
-measures untraced for the dispatch time and traces the window's last
-second with the profiler, for the per-layer metrics. After the window it
-reads the peak device memory, frees the program, runs the plain reference
-(`reference.py`) over the same three steps and compares (`check.py`).
+the pool of batches from the seed (`pool.py`, each batch drawn by the
+family), sets the optimistic term the reference also starts from, and takes
+the first three steps through the window's own call, keeping the state
+after steps 0, 1 and 3 on the host for the comparison. It warms up, then
+measures for --seconds seconds: each step is the launcher's loop body,
+synced on its metrics. With --trace 1 it measures untraced for the dispatch
+time and traces the window's last second with the profiler, for the
+per-layer metrics. After the window it reads the peak device memory, frees
+the program, runs the plain reference (`reference.py`, with the family's
+model) over the same three steps and compares (`check.py`).
 
 The last line of standard output is one JSON object: correct, attempted,
 failed, metrics, device (and breakdown with --trace 1), and last the
@@ -32,6 +38,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -58,6 +65,41 @@ def load_json(path):
         return json.load(fh)
 
 
+def load_module(path: str, name: str):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _family_module(path: str):
+    """One module object per family file and process, so that functions
+    the family hands to `jax.jit` as static arguments keep their identity
+    (and their compiled programs) from call to call."""
+    name = os.path.splitext(os.path.basename(path))[0]
+    return load_module(path, f"bench_family_{name}")
+
+
+def load_family(root: str, config: dict, path: str):
+    """The model family module that `config` (read from `path`) names in
+    its `"family"` key, loaded by path from `bench/families/` under `root`.
+    A configuration without the key, or naming no family found there, is
+    an error that names the file and the families found."""
+    fams = os.path.join(root, "bench", "families")
+    found = sorted(f[:-3] for f in os.listdir(fams)
+                   if f.endswith(".py")) if os.path.isdir(fams) else []
+    name = config.get("family")
+    if name not in found:
+        what = ("has no \"family\" key" if name is None
+                else f"names an unknown family {name!r}")
+        raise BenchError(f"{path} {what}; families found in "
+                         f"{fams}: {found}")
+    return _family_module(os.path.join(fams, name + ".py"))
+
+
 def resolve(root: str, workload: str) -> dict:
     """Everything a cell's run needs, found by the names in the manifest
     at `root`."""
@@ -74,10 +116,12 @@ def resolve(root: str, workload: str) -> dict:
         return [m for m in metrics
                 if workload in m.get("workloads", [workload])]
 
+    config_path = os.path.join(root, configs[cell["config"]]["file"])
+    config = load_json(config_path)
     return {
         "cell": cell,
-        "config": load_json(os.path.join(root,
-                                         configs[cell["config"]]["file"])),
+        "config": config,
+        "family": load_family(root, config, config_path),
         "traffic": load_json(os.path.join(bench, "traffic",
                                           cell["traffic"] + ".json")),
         "limits": load_json(os.path.join(bench, "limits",
@@ -89,14 +133,8 @@ def resolve(root: str, workload: str) -> dict:
 
 
 def reader(metrics_dir: str, name: str):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}",
-        os.path.join(metrics_dir, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(os.path.join(metrics_dir, name + ".py"),
+                       f"bench_metric_{name.replace('.', '_')}").read
 
 
 class Compiles:
@@ -185,7 +223,7 @@ def start_from_probe(prog, spec: dict, seed: int) -> None:
     from reference import lookahead_probe
 
     traffic = spec["traffic"]
-    probe = lookahead_probe(spec["config"]["gan_config"], seed,
+    probe = lookahead_probe(spec["family"], spec["config"], seed,
                             traffic["workers"], traffic["lr"])
     leaves, treedef = jax.tree.flatten(prog.state.prev_grad)
     placed = [jax.device_put(p.reshape(x.shape).astype(x.dtype), x.sharding)
@@ -213,19 +251,18 @@ def first_steps(prog, pool, spec: dict, seed: int) -> dict:
     return snap
 
 
-def reference_reals(spec: dict, seed: int):
-    """The compared steps' rows, (steps, W, batch, H, W, C), made anew from
-    the seed as the pool makes them."""
+def reference_batches(spec: dict, seed: int) -> dict:
+    """The compared steps' rows, made anew from the seed as the pool makes
+    them: each key of the batch dict stacked into (steps, W, batch, ...)."""
     import numpy as np
 
-    from pool import make_pool
-
-    gcfg, traffic = spec["config"]["gan_config"], spec["traffic"]
+    traffic = spec["traffic"]
     W, B = traffic["workers"], traffic["batch_per_worker"]
-    pool = make_pool(seed, traffic["pool_batches"], W * B,
-                     gcfg["image_size"], gcfg["channels"])
-    return np.stack([np.asarray(b["real"]).reshape(
-        (W, B) + b["real"].shape[1:]) for b in pool[:COMPARED_STEPS]])
+    pool = spec["family"].make_pool(spec["config"], seed,
+                                    traffic["pool_batches"], W * B)
+    return {k: np.stack([np.asarray(b[k]).reshape((W, B) + b[k].shape[1:])
+                         for b in pool[:COMPARED_STEPS]])
+            for k in pool[0]}
 
 
 def timed_loop(prog, pool, seconds: float):
@@ -286,23 +323,20 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
 
     import check
     import tracefmt
-    from pool import make_pool
     from program import build_program
     from reference import run_reference
 
-    cfg, traffic = spec["config"], spec["traffic"]
-    gcfg = cfg["gan_config"]
+    cfg, traffic, fam = spec["config"], spec["traffic"], spec["family"]
     chips, W = spec["cell"]["chips"], traffic["workers"]
     if W != chips:
         raise BenchError(f"traffic has {W} workers for {chips} chip(s)")
     rows = traffic["batch_per_worker"] * W
     marks = [("start", t_start), ("imports", time.perf_counter())]
-    prog = build_program(cfg, traffic, seed, chips)
+    prog = build_program(fam, cfg, traffic, seed, chips)
     if step_fault is not None:
         step_fault(prog)
     marks.append(("build", time.perf_counter()))
-    pool = make_pool(seed, traffic["pool_batches"], rows,
-                     gcfg["image_size"], gcfg["channels"])
+    pool = fam.make_pool(cfg, seed, traffic["pool_batches"], rows)
     jax.block_until_ready(pool)
     marks.append(("pool", time.perf_counter()))
     compiles = Compiles()
@@ -344,9 +378,10 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
                 finally:
                     shutil.rmtree(trace_dir, ignore_errors=True)
                 attempted = len(disp) + len(walls)
-                ctx = {"trace": tr, "config": cfg, "traffic": traffic,
-                       "dispatch_s": disp, "window_s": window_s,
-                       "steps": len(walls), "chips": chips}
+                ctx = {"trace": tr, "config": cfg, "family": fam,
+                       "traffic": traffic, "dispatch_s": disp,
+                       "window_s": window_s, "steps": len(walls),
+                       "chips": chips}
             if not trace:
                 window_compiles = compiles.n - n_before
             last = jax.device_get(prog.state.params)
@@ -392,8 +427,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     del prog, pool, last
     gc.collect()
     t_ref = time.perf_counter()
-    ref = run_reference(gcfg, traffic, seed, reference_reals(spec, seed), W,
-                        cfg["matmul_precision"])
+    ref = run_reference(fam, cfg, traffic, seed,
+                        reference_batches(spec, seed), W)
     out["setup_phases_s"] = {b[0]: b[1] - a[1] for a, b in zip(marks,
                                                                marks[1:])}
     out["setup_phases_s"]["reference, after the window"] = \
@@ -454,8 +489,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     try:
-        spec = resolve(ROOT, args.workload)
         use_compile_cache()
+        spec = resolve(ROOT, args.workload)
         dev = require_chip(spec["cell"]["chips"])
         print(f"# {tag(dev)} cell {spec['cell']['name']} seed {args.seed}",
               flush=True)

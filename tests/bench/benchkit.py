@@ -3,8 +3,8 @@ the path, cells cut to a CPU-sized smoke shape, and the four-chip mixes
 whose cells wait for chip time.
 
 The smoke shape keeps every cell's structure (workers, strategy flags,
-exchange, limits) and shrinks only the model and the batch: 8x8 images,
-latent 16, base width 8, 8 images per worker.
+exchange, limits) and shrinks only the model, to its family's `smoke` size,
+and the batch, to 8 rows per worker.
 """
 from __future__ import annotations
 
@@ -21,7 +21,6 @@ for p in (BENCH, SRC):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-SMOKE_MODEL = {"image_size": 8, "latent_dim": 16, "base_width": 8}
 SMOKE_TRAFFIC = {"batch_per_worker": 8, "pool_batches": 4}
 SEED = 2**31 + 17
 
@@ -49,9 +48,19 @@ def resolve(name: str) -> dict:
 
 def smoke(spec: dict) -> dict:
     """`spec` (from `run.resolve`) cut to the smoke shape, in place."""
-    spec["config"]["gan_config"].update(SMOKE_MODEL)
+    spec["config"] = spec["family"].smoke(spec["config"])
     spec["traffic"].update(SMOKE_TRAFFIC)
     return spec
+
+
+def config(name: str):
+    """A configuration file of `bench/configs/` and its family module, as
+    `run.resolve` finds them."""
+    import run
+
+    path = os.path.join(BENCH, "configs", name + ".json")
+    cfg = run.load_json(path)
+    return cfg, run.load_family(REPO, cfg, path)
 
 
 def run_subprocess(code: str, n_devices: int, timeout: int = 600) -> str:

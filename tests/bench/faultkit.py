@@ -25,12 +25,12 @@ def unchanged(prog):
 def half_batch(prog):
     orig, W = prog.step, prog.n_workers
 
-    def step(st, b, k, d):
-        x = b["real"]
+    def half(x):
         B = x.shape[0] // W
-        half = x.reshape((W, B) + x.shape[1:])[:, :B // 2]
-        return orig(st, {"real": half.reshape((-1,) + x.shape[1:])}, k, d)
-    prog.step = step
+        return x.reshape((W, B) + x.shape[1:])[:, :B // 2].reshape(
+            (-1,) + x.shape[1:])
+
+    prog.step = lambda st, b, k, d: orig(st, jax.tree.map(half, b), k, d)
 
 def no_lookahead(prog):
     orig = prog.step

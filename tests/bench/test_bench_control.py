@@ -4,9 +4,13 @@ float32 at the default precision, whose chip readings set the `loss_gap`
 limits; and float8 e4m3) reads `correct` false under each cell's limits,
 as do the faults planted in it (`reference.FAULTS`); a state left
 unchanged reads 1. At smoke size on the CPU; `bench/calibrate.py` reads the
-same at each cell's own size on the chip."""
+same at each cell's own size on the chip. And the sound reference itself
+is pinned: at smoke size it gives what it gave before the model-specific
+code moved into the families (`fixtures/smoke_reference.npz`, written by
+the reference of that tree from the same seed and rows)."""
 import functools
 import json
+import os
 
 import numpy as np
 import pytest
@@ -29,20 +33,19 @@ def _spec(cell):
 def _sound(cell):
     """The cell's rows and its sound reference, read once per cell."""
     spec = _spec(cell)
-    reals = run.reference_reals(spec, benchkit.SEED)
-    return reals, _reference(spec, reals)
+    batches = run.reference_batches(spec, benchkit.SEED)
+    return batches, _reference(spec, batches)
 
 
-def _reference(spec, reals, **kw):
-    gcfg, traffic = spec["config"]["gan_config"], spec["traffic"]
-    return run_reference(gcfg, traffic, benchkit.SEED, reals,
-                         traffic["workers"],
-                         spec["config"]["matmul_precision"], **kw)
+def _reference(spec, batches, **kw):
+    traffic = spec["traffic"]
+    return run_reference(spec["family"], spec["config"], traffic,
+                         benchkit.SEED, batches, traffic["workers"], **kw)
 
 
 def _numbers(cell, **kw):
-    reals, ref = _sound(cell)
-    return check.readings(_reference(_spec(cell), reals, **kw), ref), ref
+    batches, ref = _sound(cell)
+    return check.readings(_reference(_spec(cell), batches, **kw), ref), ref
 
 
 def _correct(reads, limits):
@@ -52,6 +55,27 @@ def _correct(reads, limits):
 def _faults(spec):
     return [f for f in FAULTS
             if f != "no_exchange" or spec["traffic"]["workers"] > 1]
+
+
+FIXTURE = os.path.join(benchkit.HERE, "fixtures", "smoke_reference.npz")
+
+
+@pytest.mark.parametrize("cell", ["dcgan32.q8.b64", "dcgan32.q8.b64.w4",
+                                  "dcgan32.exact.b64.w4"])
+def test_smoke_reference_pinned(cell):
+    """Losses, loss scales, the first field's leaf norms and the weights
+    after three steps equal the fixture's float32 for float32: the one-chip
+    exchange, two_phase and the exact mean. (At smoke size dcgan32-dim128
+    is dcgan32.)"""
+    _, ref = _sound(cell)
+    fx = np.load(FIXTURE)
+    for k in ("losses", "scales", "g1_norms"):
+        assert np.array_equal(np.float32(ref[k]),
+                              np.float32(fx[f"{cell}/{k}"])), k
+    assert len(ref["w3"]) == 16
+    for i, w in enumerate(ref["w3"]):
+        assert w.dtype == np.float32
+        assert np.array_equal(w, fx[f"{cell}/w3/{i}"]), i
 
 
 @pytest.mark.parametrize("cell", CELLS)

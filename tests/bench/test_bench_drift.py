@@ -18,21 +18,21 @@ CODE = r"""
 import json, sys
 import jax, numpy as np
 import benchkit, run
-from pool import make_pool
 from program import build_program
 from repro.launch import train
 
 cell, steps, seed = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
 spec = benchkit.resolve(cell)
-gc = spec["config"]["gan_config"]
-gc.update(image_size=8, channels=1, latent_dim=16, base_width=8,
-          name="dcgan32-smoke")
+fam, cfg = spec["family"], spec["config"]
+# the launcher's --smoke shape of dcgan32 (`GANConfig.reduced`)
+cfg["gan_config"].update(image_size=8, channels=1, latent_dim=16,
+                         base_width=8, name="dcgan32-smoke")
 t = spec["traffic"]
 W, B = t["workers"], 8
 t.update(batch_per_worker=B)
-pool = make_pool(seed, steps, W * B, 8, 1)
+pool = fam.make_pool(cfg, seed, steps, W * B)
 
-prog = build_program(spec["config"], t, seed, W)
+prog = build_program(fam, cfg, t, seed, W)
 with prog.context():
     for i in range(steps):
         prog.step_once(pool[i])
@@ -51,7 +51,7 @@ theirs = jax.device_get(jax.tree.leaves(res.state.params))
 same = all(np.array_equal(a, b) for a, b in zip(ours, theirs))
 moved = any(not np.array_equal(a, b) for a, b in
             zip(ours, jax.device_get(jax.tree.leaves(
-                build_program(spec["config"], t, seed, W).state.params))))
+                build_program(fam, cfg, t, seed, W).state.params))))
 print(json.dumps({"same": same, "moved": moved, "n": len(ours)}))
 """
 

@@ -1,9 +1,7 @@
-"""The model-FLOP and kernel-byte functions against hand counts, against
-XLA's count of the compiled field, and the bucket layout against the
-program's planner."""
-import json
+"""The DCGAN family's model-FLOP count and the kernel-byte functions
+against hand counts, against XLA's count of the compiled field, and pinned;
+the bucket layout against the program's planner."""
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -14,9 +12,8 @@ import benchkit
 
 import flops
 
-SMOKE = dict(json.load(open(os.path.join(
-    benchkit.BENCH, "configs", "dcgan32.json")))["gan_config"],
-    **benchkit.SMOKE_MODEL)
+CONFIG, DCGAN = benchkit.config("dcgan32")
+SMOKE = DCGAN.smoke(CONFIG)
 
 
 def _valid_products(x_shape, transpose):
@@ -35,17 +32,17 @@ def _valid_products(x_shape, transpose):
 
 @pytest.mark.parametrize("h", [2, 4, 8, 16, 32])
 def test_taps_by_convolving_ones(h):
-    assert flops.conv_taps(h) ** 2 == _valid_products((1, h, h, 1), False)
-    assert flops.conv_t_taps(h) ** 2 == _valid_products((1, h, h, 1), True)
+    assert DCGAN.conv_taps(h) ** 2 == _valid_products((1, h, h, 1), False)
+    assert DCGAN.conv_t_taps(h) ** 2 == _valid_products((1, h, h, 1), True)
 
 
 def test_smoke_hand_count():
     # 8x8x3 images, latent 16, base width 8, s0 = 1.
     # taps per axis, counted by hand: conv over 8 -> 14, over 4 -> 6,
     # over 2 -> 2; conv_transpose of 1 -> 2, of 2 -> 6, of 4 -> 14.
-    assert [flops.conv_taps(h) for h in (8, 4, 2)] == [14, 6, 2]
-    assert [flops.conv_t_taps(h) for h in (1, 2, 4)] == [2, 6, 14]
-    gen, disc = flops.dcgan_layer_macs(SMOKE)
+    assert [DCGAN.conv_taps(h) for h in (8, 4, 2)] == [14, 6, 2]
+    assert [DCGAN.conv_t_taps(h) for h in (1, 2, 4)] == [2, 6, 14]
+    gen, disc = DCGAN.layer_macs(SMOKE)
     assert gen == [16 * 32, 2**2 * 32 * 16, 6**2 * 16 * 8, 14**2 * 8 * 3]
     assert disc == [14**2 * 3 * 8, 6**2 * 8 * 16, 2**2 * 16 * 32, 32]
     g, d = sum(gen), sum(disc)
@@ -53,8 +50,30 @@ def test_smoke_hand_count():
     # L_D: D fwd on reals, dW + dX (not the first) on reals, dW on fakes
     macs = (g + d + d + g + (g - gen[0])
             + d + (d + d - disc[0]) + d)
-    assert flops.field_flops_per_image(SMOKE) == 2 * macs
-    assert flops.step_flops(SMOKE, 8, 4) == 2 * macs * 32
+    assert DCGAN.field_flops_per_image(SMOKE) == 2 * macs
+    assert DCGAN.step_flops(SMOKE, 8, 4) == 2 * macs * 32
+
+
+# The counts as they stood when the model-specific code moved into the
+# families: each cell's step at its own batch, and the parameter leaves.
+PINNED = {
+    "dcgan32": (64, 16_785_342_464, 1_849_988,
+                [64, 3072, 128, 131072, 256, 524288, 1, 4096,
+                 128, 524288, 64, 131072, 3, 3072, 4096, 524288]),
+    "dcgan32-dim128": (512, 522_840_965_120, 6_321_412,
+                       [128, 6144, 256, 524288, 512, 2097152, 1, 8192,
+                        256, 2097152, 128, 524288, 3, 6144, 8192,
+                        1048576]),
+}
+
+
+@pytest.mark.parametrize("config", sorted(PINNED))
+def test_counts_pinned(config):
+    cfg, fam = benchkit.config(config)
+    batch, step, n, sizes = PINNED[config]
+    assert fam.step_flops(cfg, batch, 1) == step
+    assert fam.param_sizes(cfg) == sizes
+    assert fam.n_params(cfg) == n == cfg["n_params"]
 
 
 def _single_backward_field(cfg):
@@ -87,11 +106,7 @@ def _single_backward_field(cfg):
 
 
 def _dcgan32():
-    from repro.models.gan import GANConfig
-
-    gc = json.load(open(os.path.join(benchkit.BENCH, "configs",
-                                     "dcgan32.json")))["gan_config"]
-    return gc, GANConfig(**gc)
+    return CONFIG, DCGAN.program_config(CONFIG)
 
 
 def _compiled_flops(field, cfg, batch):
@@ -108,14 +123,15 @@ def _compiled_flops(field, cfg, batch):
 def test_field_against_compiled_count():
     """The model count leaves out only elementwise work: within 2% of
     XLA's count of a compiled dcgan32 field at batch 64 that runs each
-    product once, and no more than the program's own field does."""
+    product once, and within 2% of the program's own field, which runs
+    each product once too."""
     from repro.models.gan import gan_field_fn
 
-    gc, cfg = _dcgan32()
-    model = flops.step_flops(gc, 64, 1)
-    once = _compiled_flops(_single_backward_field(cfg), cfg, 64)
-    assert model <= once and (once - model) / once < 0.02
-    assert model <= _compiled_flops(gan_field_fn(cfg), cfg, 64)
+    config, cfg = _dcgan32()
+    model = DCGAN.step_flops(config, 64, 1)
+    for field in (_single_backward_field(cfg), gan_field_fn(cfg)):
+        compiled = _compiled_flops(field, cfg, 64)
+        assert model <= compiled and (compiled - model) / compiled < 0.02
 
 
 def test_single_backward_field_is_the_programs():
@@ -169,16 +185,15 @@ def test_kernel_bytes_hand_count():
 @pytest.mark.parametrize("config", ["dcgan32", "dcgan32-dim128"])
 def test_buckets_match_the_planner(config, workers):
     from repro.comm import build_layout
-    from repro.models.gan import GANConfig, init
+    from repro.models import build
 
-    gc = json.load(open(os.path.join(benchkit.BENCH, "configs",
-                                     config + ".json")))["gan_config"]
+    cfg, fam = benchkit.config(config)
     shapes = jax.tree.map(
         lambda x: tuple(x.shape),
-        jax.eval_shape(lambda k: init(k, GANConfig(**gc)),
+        jax.eval_shape(build(fam.program_config(cfg)).init,
                        jax.random.key(0)))
     layout = build_layout(shapes, None, workers)
-    ours = flops.bucket_layout(flops.dcgan_param_sizes(gc), workers)
+    ours = flops.bucket_layout(fam.param_sizes(cfg), workers)
     assert [b.size for b in layout.buckets] == [s for s, _ in ours]
     assert [[s.index for s in b.slots] for b in layout.buckets] == \
         [m for _, m in ours]
@@ -190,4 +205,4 @@ def test_buckets_match_the_planner(config, workers):
             [648, 648, 512] if workers == 1 else [162, 162, 128])
     assert sum(math.prod(s) for s in jax.tree.leaves(
         shapes, is_leaf=lambda x: isinstance(x, tuple))) == \
-        flops.n_params(gc)
+        fam.n_params(cfg)

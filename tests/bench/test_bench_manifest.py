@@ -1,5 +1,6 @@
-"""BENCHMARK.json against its contract, every cell resolved by name, and a
-new cell added from data files alone."""
+"""BENCHMARK.json against its contract, every cell resolved by name, every
+configuration naming a family that builds it, and a new cell added from
+data files alone."""
 import json
 import math
 import os
@@ -10,7 +11,6 @@ import pytest
 
 import benchkit
 
-import flops
 import run
 
 with open(os.path.join(benchkit.REPO, "BENCHMARK.json")) as _fh:
@@ -94,18 +94,48 @@ def test_cell_resolves_by_name(cell):
 
 @pytest.mark.parametrize("config", [c["file"] for c in MANIFEST["configs"]])
 def test_config_sizes(config):
+    """The family's parameter sizes are those of the model the program
+    builds from the configuration, and add up to its stated count."""
     import jax
 
-    from repro.models.gan import GANConfig, init
+    from repro.models import build
 
-    with open(os.path.join(benchkit.REPO, config)) as fh:
-        cfg = json.load(fh)
-    gc = cfg["gan_config"]
-    shapes = jax.eval_shape(lambda k: init(k, GANConfig(**gc)),
+    path = os.path.join(benchkit.REPO, config)
+    cfg = run.load_json(path)
+    fam = run.load_family(benchkit.REPO, cfg, path)
+    shapes = jax.eval_shape(build(fam.program_config(cfg)).init,
                             jax.random.key(0))
     sizes = [math.prod(x.shape) for x in jax.tree.leaves(shapes)]
-    assert sizes == flops.dcgan_param_sizes(gc)
-    assert sum(sizes) == flops.n_params(gc) == cfg["n_params"]
+    assert sizes == fam.param_sizes(cfg)
+    assert sum(sizes) == fam.n_params(cfg) == cfg["n_params"]
+
+
+def _checkout(tmp_path):
+    """A checkout at `tmp_path` holding the manifest and `bench/`."""
+    root = tmp_path / "checkout"
+    shutil.copytree(benchkit.BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(benchkit.REPO, "BENCHMARK.json"), root)
+    return root
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family"])
+def test_config_family_required(tmp_path, family):
+    """A configuration with no `family` key, or naming none that is in
+    `bench/families/`, is refused, naming the file and the families
+    found."""
+    root = _checkout(tmp_path)
+    path = root / "bench/configs/dcgan32.json"
+    cfg = json.loads(path.read_text())
+    cfg.pop("family")
+    if family is not None:
+        cfg["family"] = family
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(run.BenchError) as e:
+        run.resolve(str(root), "dcgan32.q8.b64")
+    msg = str(e.value)
+    assert str(path) in msg and "['dcgan']" in msg
+    assert ("no \"family\" key" if family is None else repr(family)) in msg
 
 
 def test_new_cell_from_data_files_alone(tmp_path):
@@ -113,13 +143,12 @@ def test_new_cell_from_data_files_alone(tmp_path):
     files and a manifest entry, runs at smoke size with no code edit."""
     import jax
 
-    root = tmp_path / "checkout"
-    shutil.copytree(benchkit.BENCH, root / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    root = _checkout(tmp_path)
     manifest = json.loads(json.dumps(MANIFEST))
     cfg = json.loads((root / "bench/configs/dcgan32.json").read_text())
+    cfg = run.load_family(str(root), cfg, "dcgan32.json").smoke(cfg)
     cfg["name"] = "tiny"
-    cfg["gan_config"].update(benchkit.SMOKE_MODEL, name="tiny")
+    cfg["gan_config"]["name"] = "tiny"
     (root / "bench/configs/tiny.json").write_text(json.dumps(cfg))
     traffic = json.loads((root / "bench/traffic/q8.b64.json").read_text())
     traffic.update(benchkit.SMOKE_TRAFFIC, batch_per_worker=4)

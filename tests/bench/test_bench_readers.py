@@ -8,7 +8,6 @@ import pytest
 
 import benchkit
 
-import flops
 import run
 import tracefmt
 
@@ -58,9 +57,9 @@ def _ctx():
     spec = benchkit.smoke(benchkit.resolve("dcgan32.q8.b64.w4"))
     peaks = json.load(open(os.path.join(benchkit.BENCH, "peaks.json")))
     return {"trace": _trace(), "config": spec["config"],
-            "traffic": spec["traffic"], "dispatch_s": [0.001, 0.003],
-            "window_s": 0.001, "steps": 2, "chips": 2,
-            "peaks": peaks["devices"]["TPU v5 lite"]}
+            "family": spec["family"], "traffic": spec["traffic"],
+            "dispatch_s": [0.001, 0.003], "window_s": 0.001, "steps": 2,
+            "chips": 2, "peaks": peaks["devices"]["TPU v5 lite"]}
 
 
 def _read(name, ctx):
@@ -96,8 +95,7 @@ def test_quantize_roofline():
 
 def test_mfu_and_dispatch():
     ctx = _ctx()
-    gc = ctx["config"]["gan_config"]
-    per_step = flops.step_flops(gc, 8, 4)
+    per_step = ctx["family"].step_flops(ctx["config"], 8, 4)
     assert _read("mfu", ctx) == pytest.approx(
         100 * per_step * 2 / 0.001 / (2 * 197e12))
     assert _read("dispatch_ms", ctx) == pytest.approx(2.0)
