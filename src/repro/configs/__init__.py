@@ -34,9 +34,12 @@ _ARCH_MODULES = {
     "gemma-2b-swa": "gemma_2b_swa",
     # the paper's own experimental architecture (DCGAN-backbone GAN)
     "dcgan32": "dcgan32",
+    # the class-conditional GAN trained data-parallel on accelerators
+    "biggan128": "biggan128",
 }
 
-ASSIGNED = tuple(k for k in _ARCH_MODULES if k not in ("gemma-2b-swa", "dcgan32"))
+ASSIGNED = tuple(k for k in _ARCH_MODULES
+                 if k not in ("gemma-2b-swa", "dcgan32", "biggan128"))
 
 
 def get(name: str) -> ModelConfig:

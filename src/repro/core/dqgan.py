@@ -75,6 +75,12 @@ class DQState(NamedTuple):
     # (arXiv 2004.14180). None outside fsdp mode; replaces the
     # replicated m/v/prev_update slots, which stay None.
     fsdp: Any = None
+    # per-worker state of a stateful field (BigGAN's spectral-norm
+    # vectors), every leaf (W, *shape) sharded over the worker axes like
+    # the EF residuals: each worker's field evaluation reads and replaces
+    # its own row; never trained, exchanged, error-fed or optimized.
+    # None for a stateless field.
+    field_state: Any = None
 
 
 class StepOutput(NamedTuple):
@@ -105,7 +111,10 @@ class DQGAN:
     shim. Either way `self.strategy` is the single validated dispatch
     surface both SPMD paths consume."""
 
-    field_fn: Callable  # (params, batch, rng) -> (grad_tree, metrics_dict)
+    # (params, batch, rng) -> (grad_tree, metrics_dict). A stateful field
+    # carries `init_state(params)` (one worker's state) and is called as
+    # (params, batch, rng, state) -> (grad_tree, metrics_dict, state).
+    field_fn: Callable
     dq: Optional[DQConfig] = None
     mesh: Any = None                      # jax.sharding.Mesh | None (single proc)
     param_specs: Any = None               # pytree of PartitionSpec (model axes only)
@@ -133,6 +142,18 @@ class DQGAN:
             return 1
         return math.prod(self.mesh.shape[a]
                          for a in self.strategy.exchange.worker_axes)
+
+    @property
+    def _field_init(self):
+        """The field's `init_state`, or None for a stateless field."""
+        return getattr(self.field_fn, "init_state", None)
+
+    def _field(self, params, batch, key, fstate):
+        """(grads, metrics, new field state | None) of one evaluation."""
+        if fstate is None:
+            grads, metrics = self.field_fn(params, batch, key)
+            return grads, metrics, None
+        return self.field_fn(params, batch, key, fstate)
 
     @property
     def compressor(self) -> C.Compressor:
@@ -323,6 +344,11 @@ class DQGAN:
             for b in layout.buckets:
                 fb[str(b.bid)]["w"] = flats[b.bid].reshape(W, b.size // W)
             st = st._replace(fsdp=fb)
+        if self._field_init is not None:
+            W = max(self.n_workers, 1)
+            st = st._replace(field_state=jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (W,) + x.shape),
+                self._field_init(params)))
         if self.mesh is None:
             return st
         return jax.tree.map(lambda x, s: jax.device_put(x, s.sharding),
@@ -495,6 +521,11 @@ class DQGAN:
             versions_like=lambda: sds((W,), jnp.int32, P(axes)),
         )
 
+        field_state = None
+        if self._field_init is not None:
+            field_state = jax.tree.map(
+                per_worker_like, jax.eval_shape(self._field_init, params))
+
         return DQState(
             step=sds((), jnp.int32, P()),
             params=params_s,
@@ -505,6 +536,7 @@ class DQGAN:
             v=v,
             sched=sched,
             fsdp=fsdp,
+            field_state=field_state,
         )
 
     def state_specs(self, params) -> DQState:
@@ -570,7 +602,8 @@ class DQGAN:
             sub = getattr(state, name)
             if sub is None:
                 return None
-            lead = (wlead if name in ("prev_grad", "ef", "sched", "fsdp")
+            lead = (wlead if name in ("prev_grad", "ef", "sched", "fsdp",
+                                      "field_state")
                     else rep)
             return jax.tree.map(lambda _: lead, sub)
 
@@ -584,6 +617,7 @@ class DQGAN:
             v=st_spec("v"),
             sched=st_spec("sched"),
             fsdp=st_spec("fsdp"),
+            field_state=st_spec("field_state"),
         )
         bspec = self.batch_spec
         if bspec is None:
@@ -657,7 +691,7 @@ class DQGAN:
         col = (OBS.Collector(obs_spec, 0) if obs_spec.on
                else OBS.NullCollector())
 
-        def worker(prev_g, ef, sw, b, i, mask):
+        def worker(prev_g, ef, sw, fs, b, i, mask):
             kw = jax.random.fold_in(jax.random.fold_in(key, i), state.step)
             kf, kq = jax.random.split(kw)
             pending_buf, pending = sched_c.wire_head(sw, i)
@@ -692,7 +726,7 @@ class DQGAN:
                 else:
                     w_half = state.params
             with OBS.device_span("field", spans):
-                grads, metrics = self.field_fn(w_half, b, kf)
+                grads, metrics, new_fs = self._field(w_half, b, kf, fs)
             if dq.message == "update" and dq.optimizer == "omd":
                 msg = jax.tree.map(lambda g: (eta * g).astype(jnp.float32),
                                    grads)
@@ -731,15 +765,17 @@ class DQGAN:
                 phat = jax.tree.unflatten(treedef, phats)
                 enew = (jax.tree.unflatten(treedef, enews)
                         if dq.error_feedback else None)
-            return (phat, enew, new_sw, grads,
+            return (phat, enew, new_sw, new_fs, grads,
                     metrics.get("loss", jnp.zeros(())), col.sums())
 
         prev_g = state.prev_grad
         ef = state.ef if dq.error_feedback else None
-        phat_w, ef_w, sched_w, grads_w, loss_w, obs_sums_w = jax.vmap(
+        (phat_w, ef_w, sched_w, fs_w, grads_w, loss_w,
+         obs_sums_w) = jax.vmap(
             worker,
-            in_axes=(0, 0 if ef is not None else None, 0, 0, 0, 0),
-        )(prev_g, ef, state.sched, batch_w, widx, mask_vec)
+            in_axes=(0, 0 if ef is not None else None, 0, 0, 0, 0, 0),
+        )(prev_g, ef, state.sched, state.field_state, batch_w, widx,
+          mask_vec)
 
         new_m, new_v, new_prev_update = state.m, state.v, state.prev_update
         new_ef = state.ef
@@ -771,7 +807,7 @@ class DQGAN:
         new_state = DQState(
             step=state.step + 1, params=new_params, prev_grad=new_prev_grad,
             prev_update=new_prev_update, ef=new_ef, m=new_m, v=new_v,
-            sched=new_sched)
+            sched=new_sched, field_state=fs_w)
         gn = _global_norm(grads_w)
         en = _global_norm(new_ef) if new_ef is not None else jnp.zeros(())
         if schedule == "delayed":
@@ -838,6 +874,7 @@ class DQGAN:
         ef = takew(state.ef)
         sched_st = takew(state.sched)
         fsdp_st = takew(state.fsdp)
+        field_st = takew(state.field_state)
         # pending_buf: the raw delayed-schedule buffer (ring for τ>1);
         # pending: the message on the wire THIS step (its oldest slot, or
         # this worker's τ_m pull slot under a heterogeneous tau_vector)
@@ -926,7 +963,8 @@ class DQGAN:
 
         # ---------- local stochastic field -------------------------------- #
         with OBS.device_span("field", self._obs_spans):
-            grads, metrics = self.field_fn(w_half, batch, kfield)
+            grads, metrics, new_field_st = self._field(w_half, batch, kfield,
+                                                       field_st)
 
         # ---------- message + schedule dataflow --------------------------- #
         if dq.message == "update" and dq.optimizer == "omd":
@@ -1018,6 +1056,7 @@ class DQGAN:
             v=new_v,
             sched=putw(new_sched),
             fsdp=putw(new_fsdp),
+            field_state=putw(new_field_st),
         )
         out_metrics = {"loss": loss, "grad_norm": gn, "error_norm": en,
                        "staleness_max": st_max, "staleness_mean": st_mean}

@@ -70,15 +70,19 @@ def gaussian_mixture_sampler(n_modes=8, radius=2.0, std=0.05):
 # --------------------------------------------------------------------------- #
 # procedural images (CIFAR stand-in)
 # --------------------------------------------------------------------------- #
-def procedural_images(key, n, size=32, channels=3):
+def procedural_images(key, n, size=32, channels=3, hue=None):
     """Images of a randomly-placed, randomly-oriented Gaussian blob with a
     color gradient — structured enough that a GAN must learn position,
-    orientation and color jointly. Values in [-1, 1]."""
+    orientation and color jointly. Values in [-1, 1]. `hue`, (n,) in
+    [0, 1), fixes each image's color; drawn per channel when None."""
     ks = jax.random.split(key, 5)
     cx = jax.random.uniform(ks[0], (n, 1, 1, 1), minval=0.25, maxval=0.75)
     cy = jax.random.uniform(ks[1], (n, 1, 1, 1), minval=0.25, maxval=0.75)
     sig = jax.random.uniform(ks[2], (n, 1, 1, 1), minval=0.05, maxval=0.15)
-    hue = jax.random.uniform(ks[3], (n, 1, 1, channels))
+    if hue is None:
+        hue = jax.random.uniform(ks[3], (n, 1, 1, channels))
+    else:
+        hue = hue.reshape(n, 1, 1, 1)
     yy, xx = jnp.meshgrid(jnp.linspace(0, 1, size), jnp.linspace(0, 1, size),
                           indexing="ij")
     grid_x = xx[None, :, :, None]
@@ -88,3 +92,12 @@ def procedural_images(key, n, size=32, channels=3):
     color = 0.5 + 0.5 * jnp.sin(phase)
     img = blob * color + 0.1 * (grid_x + grid_y) - 0.5
     return jnp.clip(2 * img, -1, 1)
+
+
+def labelled_images(key, n, size, channels, n_classes):
+    """(images, labels) for a class-conditional GAN: labels uniform over
+    `n_classes`, each image's color set by its label."""
+    kl, ki = jax.random.split(key)
+    labels = jax.random.randint(kl, (n,), 0, n_classes)
+    return (procedural_images(ki, n, size, channels,
+                              hue=labels / n_classes), labels)

@@ -69,8 +69,10 @@ inside it, on the host):
 
 Checkpointing: ``--checkpoint PATH`` saves the FULL ``DQState`` (params,
 optimizer moments, prev_grad, EF residuals incl. comm-plan bucket
-entries, schedule buffers) at the end and every ``--checkpoint-every N``
-steps; ``--resume PATH`` restores it and continues from the saved step.
+entries, schedule buffers, a stateful field's per-worker state such as
+biggan128's spectral-norm vectors) at the end and every
+``--checkpoint-every N`` steps; ``--resume PATH`` restores it and
+continues from the saved step.
 
 For the paper's own experiment (DCGAN), use examples/train_gan_images.py
 which adds the WGAN weight clipping + evaluation metrics.
@@ -427,14 +429,21 @@ def run(argv=None) -> TrainRun:
 
 
 def gan_batch_iterator(seed, batch, cfg):
-    """Procedural-image batches for GANConfig archs (dcgan32)."""
-    from repro.data import procedural_images
+    """Procedural-image batches for the GAN archs: {"real"} (dcgan32), and
+    {"real", "label"} for a class-conditional one (biggan128)."""
+    from repro.data import labelled_images, procedural_images
 
     key = jax.random.key(seed)
     i = 0
     while True:
-        yield {"real": procedural_images(jax.random.fold_in(key, i), batch,
-                                         cfg.image_size, cfg.channels)}
+        k = jax.random.fold_in(key, i)
+        if getattr(cfg, "n_classes", 0):
+            real, label = labelled_images(k, batch, cfg.image_size,
+                                          cfg.channels, cfg.n_classes)
+            yield {"real": real, "label": label}
+        else:
+            yield {"real": procedural_images(k, batch, cfg.image_size,
+                                             cfg.channels)}
         i += 1
 
 

@@ -10,6 +10,10 @@ Loss: WGAN (paper Eq. 3):
     L_D = -E_x[D(x)] + E_z[D(G(z))]       L_G = -E_z[D(G(z))]
 The min-max field (paper Eq. 10) is F(w) = [∇θ L_G, ∇φ L_D] — that is what
 DQGAN exchanges/averages across workers.
+
+BigGAN (`models/biggan.py`) takes the hinge loss instead, on
+class-labelled batches, with the spectral-norm vectors as the field's state
+(`hinge_field_fn`).
 """
 from __future__ import annotations
 
@@ -19,6 +23,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from . import biggan
 from .layers import linear, linear_init
 
 
@@ -145,19 +150,24 @@ def mlp_discriminate(disc, cfg, x):
 # --------------------------------------------------------------------------- #
 # the min-max field (what DQGAN transports)
 # --------------------------------------------------------------------------- #
-def init(key, cfg: GANConfig, max_seq: int = 0):
+def init(key, cfg, max_seq: int = 0):
     del max_seq
+    if isinstance(cfg, biggan.BigGANConfig):
+        return biggan.init(key, cfg)
     return (dcgan_init if cfg.is_image else mlp_gan_init)(key, cfg)
 
 
-def gan_field_fn(cfg: GANConfig):
+def gan_field_fn(cfg):
     """Returns field_fn(params, batch, rng) -> (grads, metrics) for DQGAN.
-    batch: {"real": real samples}.
+    batch: {"real": real samples}. A BigGAN config gets the stateful
+    hinge field of `hinge_field_fn` instead.
 
     The critic's backward pass on the fakes runs once, shared by both
     losses: its VJP seeded with +1/B gives L_D's weight-gradients there, and
     its input cotangent, negated, is L_G's, fed to the generator's VJP. The
     reals take weight-gradients only."""
+    if isinstance(cfg, biggan.BigGANConfig):
+        return hinge_field_fn(cfg)
     gen_f = dcgan_generate if cfg.is_image else mlp_generate
     disc_f = dcgan_discriminate if cfg.is_image else mlp_discriminate
 
@@ -179,6 +189,60 @@ def gan_field_fn(cfg: GANConfig):
         return ({"gen": g_gen, "disc": g_disc},
                 {"loss": ld + lg, "loss_g": lg, "loss_d": ld})
 
+    return field_fn
+
+
+def hinge_field_fn(cfg: biggan.BigGANConfig):
+    """The BigGAN hinge field, a stateful field for DQGAN:
+    field_fn(params, batch, rng, sn_state) -> (grads, metrics, sn_state),
+    and field_fn.init_state(params) -> the spectral-norm vectors u.
+    batch: {"real": (B, H, W, 3), "label": (B,)}; the fakes' z and classes
+    are drawn from rng (`biggan.draw`).
+
+        L_D = E relu(1 - D(x, y)) + E relu(1 + D(G(z, y_f), y_f))
+        L_G = -E D(G(z, y_f), y_f)
+
+    F = [grad L_G in G, disc_grad_mult x grad L_D in D]. One power
+    iteration per weight normalizes the parameters, shared by the reals,
+    the fakes and both losses; the field's cotangent on the normalized
+    parameters is pulled back through it once. D's backward pass on the
+    fakes runs twice, with the two losses' cotangents there: L_D's
+    weight-gradients take 1[1 + D > 0] / B, L_G's input-gradients -1 / B.
+    The reals take weight-gradients only, under 1[1 - D > 0] / B.
+    Metrics add the share of rows whose hinge is active on each side."""
+    eps = cfg.SN_eps
+
+    def field_fn(params, batch, rng, sn_state):
+        real, y_real = batch["real"], batch["label"]
+        z, y_fake = biggan.draw(cfg, rng, real.shape[0])
+        normed, sn_vjp, new_state = jax.vjp(
+            lambda p: biggan.spectral_norm(p, sn_state, eps), params,
+            has_aux=True)
+        fake, gen_vjp = jax.vjp(
+            lambda g: biggan.generate(g, cfg, z, y_fake), normed["gen"])
+        d_fake, fake_vjp = jax.vjp(
+            lambda d, x: biggan.discriminate(d, cfg, x, y_fake),
+            normed["disc"], fake)
+        d_real, real_vjp = jax.vjp(
+            lambda d: biggan.discriminate(d, cfg, real, y_real),
+            normed["disc"])
+        act_real = (1.0 - d_real > 0).astype(d_real.dtype)
+        act_fake = (1.0 + d_fake > 0).astype(d_fake.dtype)
+        gd_fake, _ = fake_vjp(act_fake / d_fake.size)
+        _, gx_fake = fake_vjp(jnp.full_like(d_fake, -1 / d_fake.size))
+        (gd_real,) = real_vjp(-act_real / d_real.size)
+        (g_gen,) = gen_vjp(gx_fake)
+        g_disc = jax.tree.map(lambda r, f: cfg.disc_grad_mult * (r + f),
+                              gd_real, gd_fake)
+        (grads,) = sn_vjp({"gen": g_gen, "disc": g_disc})
+        lg = -jnp.mean(d_fake)
+        ld = (jnp.mean(jax.nn.relu(1.0 - d_real))
+              + jnp.mean(jax.nn.relu(1.0 + d_fake)))
+        return grads, {"loss": ld + lg, "loss_g": lg, "loss_d": ld,
+                       "hinge_active_real": jnp.mean(act_real),
+                       "hinge_active_fake": jnp.mean(act_fake)}, new_state
+
+    field_fn.init_state = biggan.init_sn_state
     return field_fn
 
 

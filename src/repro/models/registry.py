@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from . import biggan
 from . import gan as gan_lib
 from . import model as lm
 
@@ -17,7 +18,10 @@ from . import model as lm
 class ModelBundle:
     cfg: Any
     init: Callable                  # (key, max_seq) -> params
-    field_fn: Callable              # (params, batch, rng) -> (grads, metrics)
+    # (params, batch, rng) -> (grads, metrics); a stateful field (BigGAN's
+    # spectral-norm vectors) takes and returns its state too and carries
+    # `init_state(params)`, see core.dqgan.DQGAN
+    field_fn: Callable
     loss_fn: Optional[Callable]     # (params, batch) -> (loss, metrics)
     prefill: Optional[Callable]     # (params, tokens[, enc]) -> (logits, cache)
     decode_step: Optional[Callable]
@@ -28,7 +32,7 @@ class ModelBundle:
 
 
 def build(cfg) -> ModelBundle:
-    if isinstance(cfg, gan_lib.GANConfig):
+    if isinstance(cfg, (gan_lib.GANConfig, biggan.BigGANConfig)):
         return ModelBundle(
             cfg=cfg,
             init=lambda key, max_seq=0: gan_lib.init(key, cfg),
